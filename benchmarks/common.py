@@ -1,6 +1,7 @@
 """Shared helpers for the benchmark harness."""
 from __future__ import annotations
 
+import os
 import time
 from typing import Callable, Tuple
 
@@ -44,3 +45,18 @@ def json_arg(argv, default: str = "BENCH_search.json"):
     if i + 1 < len(argv) and not argv[i + 1].startswith("-"):
         return argv[i + 1]
     return default
+
+
+def respawn_for_host_devices(n: int) -> bool:
+    """Whether a bench that needs ``n`` devices should re-run itself in a
+    child with ``n`` faked host devices.  Only on the CPU platform
+    (``JAX_PLATFORMS=cpu``) whose ``XLA_FLAGS`` ask for fewer, and only
+    while this process has not initialised a JAX backend: a process that
+    has touched JAX may hold the chip, and a child could not have it.
+    Otherwise the bench runs in-process on the devices it is given."""
+    from jax._src import xla_bridge
+
+    from repro.launch.mesh import requested_host_devices
+    return (os.environ.get("JAX_PLATFORMS") == "cpu"
+            and (requested_host_devices() or 1) < n
+            and not xla_bridge.backends_are_initialized())

@@ -3,8 +3,10 @@ sharded-vs-single-device equivalence flags -> ``BENCH_decode.json``.
 
 For each transformer spec and node count this searches a decode plan
 (head-sharding testbed), runs greedy decode through :class:`DecodeSession`
-on the local executor and on the mesh executor (8 fake host devices,
-respawn pattern shared with ``mesh_bench``), and records:
+on the local executor and on the mesh executor (one device per node:
+points with more nodes than devices skip the mesh run; on the CPU the
+bench fakes 8 host devices, respawn pattern shared with ``mesh_bench``),
+and records:
 
 * ``head_sharded`` — the planner chose OutC on every ATTN step (the
   decode-graph cost physics held up);
@@ -31,7 +33,7 @@ import os
 import subprocess
 import sys
 
-from .common import emit, json_arg
+from .common import emit, json_arg, respawn_for_host_devices
 
 #: OutC-friendly decode testbed (SRIO-class link latency) — matches the
 #: equivalence suite in tests/test_decode.py
@@ -59,12 +61,14 @@ NOISE_NOTE = (
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+#: host devices the CPU platform fakes for the mesh runs
 MESH_DEVICES = 8
 
 
 def _bench_point(spec_name: str, nodes: int, full: bool) -> dict:
     import time
 
+    import jax
     import numpy as np
     from repro.core import Scheme, Testbed
     from repro.runtime.decode import (DecodeSession, TransformerSpec,
@@ -104,7 +108,7 @@ def _bench_point(spec_name: str, nodes: int, full: bool) -> dict:
     ok_local, rel_err, prefill_s, decode_s = _decode(ExecConfig())
 
     ok_mesh = None
-    if nodes <= MESH_DEVICES:
+    if nodes <= len(jax.devices()):
         ok_mesh, _, _, _ = _decode(ExecConfig(executor="mesh"))
 
     rec = {
@@ -125,7 +129,9 @@ def _bench_point(spec_name: str, nodes: int, full: bool) -> dict:
 
 def _run_inner(json_path: str | None, smoke: bool) -> dict:
     import jax
-    assert len(jax.devices()) >= MESH_DEVICES, jax.devices()
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     grid = SMOKE if smoke else FULL
     record = {"devices": len(jax.devices()), "noise_note": NOISE_NOTE,
               "prompt_len": PROMPT_LEN, "n_new": N_NEW, "kv_len": KV_LEN,
@@ -151,11 +157,11 @@ def _run_inner(json_path: str | None, smoke: bool) -> dict:
 
 
 def run(json_path: str | None = None, smoke: bool = False) -> dict:
-    """Entry point used by ``benchmarks.run``: respawns in a subprocess
-    with forced host devices when this process is short of them (jax
-    device count is fixed at init — same pattern as ``mesh_bench``)."""
-    import jax
-    if len(jax.devices()) >= MESH_DEVICES:
+    """Entry point used by ``benchmarks.run``: runs in-process, or
+    respawns with forced host devices where
+    :func:`~benchmarks.common.respawn_for_host_devices` allows (same
+    pattern as ``mesh_bench``)."""
+    if not respawn_for_host_devices(MESH_DEVICES):
         return _run_inner(json_path, smoke)
     out_path = os.path.abspath(json_path) if json_path else \
         os.path.join(_ROOT, "BENCH_decode.json")

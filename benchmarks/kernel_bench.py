@@ -32,6 +32,7 @@ from repro.configs.edge_models import EDGE_MODELS
 from repro.core import Testbed
 from repro.core.dpp import plan_search
 from repro.kernels.conv2d import conv2d_shard
+from repro.kernels.mode import interpret_mode
 from repro.kernels.ops import matmul_tiled
 from repro.kernels.ref import conv2d_shard_ref, matmul_ref
 from repro.runtime.engine import init_weights
@@ -136,7 +137,7 @@ def _bench_equiv(model: str, kw: dict) -> dict:
 
 
 def run(json_path: str | None = None) -> dict:
-    out: dict = {"interpret": jax.default_backend() != "tpu",
+    out: dict = {"interpret": interpret_mode(),
                  "kernels": {}, "backend_equiv": {}}
     for name, geo in _SHARD_CASES:
         rec = _bench_shard(name, geo)
@@ -167,4 +168,6 @@ def run(json_path: str | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(json_path=json_arg(sys.argv[1:], default="BENCH_kernels.json"))

@@ -34,10 +34,11 @@ ratios reflect the analytic Testbed's modeled edge silicon, not this
 host.  The flags are the contract; the times are the trajectory record
 (see ``noise_note`` in the JSON).
 
-The bench needs >= 4 devices: when the current process has fewer it
-respawns itself in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (jax device count
-is fixed at init, so the flag cannot be applied in-process).
+The bench needs >= 4 devices.  It runs in-process on the devices it is
+given; only on the CPU platform, before this process has initialised JAX,
+does it respawn itself with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` when the flags ask
+for fewer (``common.respawn_for_host_devices``).
 """
 from __future__ import annotations
 
@@ -46,7 +47,8 @@ import os
 import subprocess
 import sys
 
-from .common import emit, json_arg, trace_dir_arg
+from .common import (emit, json_arg, respawn_for_host_devices,
+                     trace_dir_arg)
 
 NODES = 4
 
@@ -149,7 +151,14 @@ def _bench_model(name: str, trace_dir: str | None = None) -> dict:
 def _run_inner(json_path: str | None, smoke: bool,
                trace_dir: str | None = None) -> dict:
     import jax
-    assert len(jax.devices()) >= NODES, jax.devices()
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    if len(jax.devices()) < NODES:
+        raise RuntimeError(
+            f"mesh_bench needs {NODES} devices, found {len(jax.devices())} "
+            f"(on the CPU set XLA_FLAGS=--xla_force_host_platform_device_"
+            f"count={NODES} before JAX starts)")
     if trace_dir:
         os.makedirs(trace_dir, exist_ok=True)
         from repro.obs import Metrics, set_metrics
@@ -177,10 +186,10 @@ def _run_inner(json_path: str | None, smoke: bool,
 
 def run(json_path: str | None = None, smoke: bool = False,
         trace_dir: str | None = None) -> dict:
-    """Entry point used by ``benchmarks.run``: respawns in a subprocess
-    with forced host devices when this process is short of them."""
-    import jax
-    if len(jax.devices()) >= NODES:
+    """Entry point used by ``benchmarks.run``: runs in-process, or
+    respawns with forced host devices where
+    :func:`~benchmarks.common.respawn_for_host_devices` allows."""
+    if not respawn_for_host_devices(NODES):
         return _run_inner(json_path, smoke, trace_dir=trace_dir)
     out_path = os.path.abspath(json_path) if json_path else \
         os.path.join(_ROOT, "BENCH_mesh.json")

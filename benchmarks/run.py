@@ -5,11 +5,20 @@
 # the mesh and churn benches into PATH (see repro.obs).
 from __future__ import annotations
 
+import os
 import sys
 
 
 def main(argv: list[str] | None = None) -> None:
     argv = sys.argv[1:] if argv is None else argv
+    if os.environ.get("JAX_PLATFORMS") == "cpu" \
+            and "--xla_force_host_platform_device_count" not in \
+            os.environ.get("XLA_FLAGS", ""):
+        # every bench runs in this process: give the CPU platform the
+        # mesh and decode benches' fake devices before JAX starts
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=8").strip()
     from .common import json_arg, trace_dir_arg
     json_path = json_arg(argv)
     trace_dir = trace_dir_arg(argv)
@@ -18,6 +27,8 @@ def main(argv: list[str] | None = None) -> None:
                    estimator_quality, fig2_microbench,
                    fig7_fig9_comparison, fig8_score, kernel_bench,
                    mesh_bench, roofline_table, search_time, sweep, tpu_ce)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     fig2_microbench.run()
     fig7_fig9_comparison.run(4, "fig7")

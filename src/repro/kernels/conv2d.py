@@ -2,27 +2,30 @@
 
 The edge engine's partitioned inference runs conv shards with halo rows
 (§2.3 of the paper).  :func:`conv2d_shard` is the TPU-native version of one
-shard's compute and consumes the NT-mode shard layout *directly*: the local
-input slice — its own rows plus the halo rows backward-chained from the
-segment tail — lands in VMEM as-is, and any zero padding at the graph
-boundary is applied once into a VMEM scratch buffer on the first grid step
-(``pl.when(i == 0)``; scratch persists across the sequential grid), so no
-padded copy of the feature map is ever re-materialized in HBM per segment
-layer.
+shard's compute and consumes the NT-mode shard layout: the local input
+slice — its own rows plus the halo rows backward-chained from the segment
+tail.  The shard's per-side zero padding at the graph boundary is applied
+once in HBM, where a stride-``s`` shard is also split into its ``s*s``
+row/column phases (:func:`_phases`).  The shard stays in HBM: each output
+tile's halo-extended row window is an overlapping ``pl.Element`` block, so
+the Pallas pipeline streams one window per tile (double-buffered) and peak
+VMEM depends on the tile height and the row width, never on the shard
+height.
 
 The compute is im2col without materializing the im2col matrix: the output
 is tiled by rows and each (kh, kw) kernel tap is an MXU matmul
-``[tile_h*W, Cin] @ [Cin, Cout]`` accumulated in f32.  Strided convs load
-the contiguous tap span and re-stride in registers; depthwise convs replace
-the tap matmul with a VPU broadcast-multiply.  A tile deliberately reads
-``K-1`` rows past its own range — exactly the redundant-compute region the
-planner accounts for.
+``[tile_h*W, Cin] @ [Cin, Cout]`` accumulated in f32 at f32 contraction
+precision (``Precision.HIGHEST``).  Thanks to the phase split every tap of
+a strided conv is a contiguous stride-1 window of one phase — the TPU
+compiler lowers neither a strided value slice nor a strided ref load of
+these layouts.  Depthwise convs replace the tap matmul with a VPU
+broadcast-multiply.  A tile deliberately reads ``K-1`` rows past its own
+range — exactly the redundant-compute region the planner accounts for.
 
 Degenerate geometries (``out_h <= 0`` or ``out_w <= 0`` after padding)
 raise :class:`UnsupportedGeometry`; callers (``ops.conv2d``, the engine's
-pallas backend) catch it and fall back to the XLA path.  Validated with
-``interpret=True`` (this container is CPU-only); the grid/BlockSpec/scratch
-structure is the TPU deployment artifact.
+pallas backend) route those to the XLA path.  Off the TPU the kernel runs
+in interpret mode (:func:`repro.kernels.mode.interpret_mode`).
 
 The kernel is executor-agnostic: the single-process engine hands it the
 host-sliced local input, and the mesh executor
@@ -35,12 +38,14 @@ drop-in.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .mode import interpret_mode
 
 Pads = Tuple[int, int, int, int]   # (top, bottom, left, right)
 
@@ -60,45 +65,47 @@ def shard_out_shape(in_h: int, in_w: int, k: int, stride: int,
     return out_h, out_w
 
 
-def _shard_kernel(x_ref, w_ref, o_ref, xp_ref, *, k: int, stride: int,
-                  pads: Pads, tile_h: int, out_w: int, cin: int, cout: int,
-                  depthwise: bool, in_h: int, in_w: int):
-    i = pl.program_id(0)
-    pt, _, pl_, _ = pads
+def _phases(xp: jnp.ndarray, s: int) -> jnp.ndarray:
+    """[R, C, cin] (R, C multiples of ``s``) -> [s*s, R/s, C/s, cin]:
+    phase ``a*s + b`` holds rows ``a::s`` and columns ``b::s``, so every
+    tap of a stride-``s`` conv is a contiguous stride-1 window of one
+    phase."""
+    R, C, cin = xp.shape
+    if s == 1:
+        return xp[None]
+    return xp.reshape(R // s, s, C // s, s, cin).transpose(
+        1, 3, 0, 2, 4).reshape(s * s, R // s, C // s, cin)
 
-    @pl.when(i == 0)
-    def _fill_scratch():
-        # one VMEM zero-fill for the whole shard; halo rows arrive in the
-        # raw input and are consumed in place (never copied through HBM)
-        xp_ref[...] = jnp.zeros_like(xp_ref)
-        xp_ref[pt:pt + in_h, pl_:pl_ + in_w, :] = x_ref[...]
 
-    rspan = (tile_h - 1) * stride + 1
-    cspan = (out_w - 1) * stride + 1
+def _shard_kernel(x_ref, w_ref, o_ref, *, k: int, stride: int,
+                  tile_h: int, out_w: int, cin: int, cout: int,
+                  depthwise: bool):
+    s = stride
     if depthwise:
         acc = jnp.zeros((tile_h, out_w, cout), jnp.float32)
     else:
         acc = jnp.zeros((tile_h * out_w, cout), jnp.float32)
     for kh in range(k):
         for kw in range(k):
-            # logical padded rows [i*tile_h*s + kh, ...) strided by s
-            span = xp_ref[pl.dslice(i * tile_h * stride + kh, rspan),
-                          pl.dslice(kw, cspan), :]
-            xs = span[::stride, ::stride, :].astype(jnp.float32)
+            # tap (kh, kw): phase (kh % s, kw % s) at offset
+            # (kh // s, kw // s) of this tile's window
+            xs = x_ref[(kh % s) * s + kw % s, pl.ds(kh // s, tile_h),
+                       pl.ds(kw // s, out_w), :].astype(jnp.float32)
             if depthwise:
-                acc = acc + xs * w_ref[kh, kw, 0].astype(jnp.float32)
+                acc = acc + xs * w_ref[kh, kw].astype(jnp.float32)
             else:
-                xm = xs.reshape(tile_h * out_w, cin)
-                wm = w_ref[kh, kw].astype(jnp.float32)      # [cin, cout]
                 acc = acc + jax.lax.dot_general(
-                    xm, wm, (((1,), (0,)), ((), ())),
+                    xs.reshape(tile_h * out_w, cin),
+                    w_ref[kh, kw].astype(jnp.float32),
+                    (((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32)
     o_ref[...] = acc.reshape(tile_h, out_w, cout).astype(o_ref.dtype)
 
 
 def conv2d_shard(x: jnp.ndarray, w: jnp.ndarray, *, pads: Pads = (0, 0, 0, 0),
                  stride: int = 1, depthwise: bool = False, tile_h: int = 8,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """One conv shard over the NT-mode local layout.
 
     ``x``: [Hl, Wl, Cin] — the node's raw input slice, halo rows included,
@@ -119,35 +126,41 @@ def conv2d_shard(x: jnp.ndarray, w: jnp.ndarray, *, pads: Pads = (0, 0, 0, 0),
         raise UnsupportedGeometry(
             f"degenerate output {out_h}x{out_w}x{cout} for input "
             f"{Hl}x{Wl}x{cin}, k={K}, s={stride}, pads={pads}")
-    pt, pb, pl_, pr = pads
+    s = stride
+    pt, _, pl_, _ = pads
     tile_h = max(1, min(tile_h, out_h))
     nt = -(-out_h // tile_h)
-    # scratch must cover the last tile's deepest tap row (padded rows past
-    # out_h are computed then dropped)
-    rows = max(Hl + pt + pb, (nt * tile_h - 1) * stride + K)
-    cols = Wl + pl_ + pr
+    q = (K - 1) // s                      # tap reach, in phase rows/cols
+    # padded extent: every tap of every tile (rows past out_h are computed
+    # then dropped); input rows/cols no output reads are cropped
+    R, C = s * (nt * tile_h + q), s * (out_w + q)
+    xp = jnp.pad(x, ((pt, max(0, R - Hl - pt)), (pl_, max(0, C - Wl - pl_)),
+                     (0, 0)))[:R, :C]
+    xph = _phases(xp, s)                  # [s*s, R/s, C/s, cin]
     kernel = functools.partial(
-        _shard_kernel, k=K, stride=stride, pads=pads, tile_h=tile_h,
-        out_w=out_w, cin=cin, cout=cout, depthwise=depthwise,
-        in_h=Hl, in_w=Wl)
+        _shard_kernel, k=K, stride=s, tile_h=tile_h, out_w=out_w,
+        cin=cin, cout=cout, depthwise=depthwise)
     out = pl.pallas_call(
         kernel,
         grid=(nt,),
         in_specs=[
-            pl.BlockSpec(x.shape, lambda i: (0, 0, 0)),     # shard in VMEM
+            # tile i's halo-extended window: phase rows
+            # [i*tile_h, i*tile_h + tile_h + q), overlapping its neighbours
+            pl.BlockSpec((pl.Element(s * s), pl.Element(tile_h + q),
+                          pl.Element(C // s), pl.Element(cin)),
+                         lambda i: (0, i * tile_h, 0, 0)),
             pl.BlockSpec(w.shape, lambda i: (0, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((tile_h, out_w, cout), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nt * tile_h, out_w, cout), x.dtype),
-        scratch_shapes=[pltpu.VMEM((rows, cols, cin), x.dtype)],
-        interpret=interpret,
-    )(x, w)
+        interpret=interpret_mode(interpret),
+    )(xph, w)
     return out[:out_h]
 
 
 def conv2d_tiled(x: jnp.ndarray, w: jnp.ndarray, *, padding: int = 0,
                  stride: int = 1, tile_h: int = 8,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """Full-tensor convenience form: x [H, W, Cin] unpadded, symmetric
     ``padding``.  Thin wrapper over :func:`conv2d_shard` (a one-shard
     "plan"); kept as the historical public name."""

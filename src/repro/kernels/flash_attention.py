@@ -8,9 +8,9 @@ never leaves VMEM; q blocks of ``block_q`` rows stream over k/v blocks of
 ``block_q*hd + 2*block_k*hd + block_q*block_k`` floats, comfortably inside
 the ~16 MiB VMEM for hd <= 256.
 
-Validated on CPU via ``interpret=True`` against ``ref.attention_ref`` (the
-container has no TPU); the grid/BlockSpec structure is the TPU deployment
-artifact.
+Off the TPU both kernels run in interpret mode
+(:func:`repro.kernels.mode.interpret_mode`); their dots contract at f32
+precision (``Precision.HIGHEST``).
 """
 from __future__ import annotations
 
@@ -21,8 +21,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .mode import interpret_mode
 
 NEG_INF = -1e30
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
@@ -40,6 +44,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
         kb = k_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
         vb = v_ref[0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                                precision=_F32,
                                 preferred_element_type=jnp.float32)
         k_idx = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
@@ -54,7 +59,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
         p = jnp.exp(s - m_new[:, None])
         l_new = l * corr + p.sum(axis=-1)
         acc_new = acc * corr[:, None] + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
+            p, vb, (((1,), (0,)), ((), ())), precision=_F32,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
@@ -87,7 +92,7 @@ def _decode_kernel(seq_ref, pt_ref, q_ref, kp_ref, vp_ref, o_ref, *,
     or beyond ``kv_len`` are masked instead.
     """
     kv_len = seq_ref[0]
-    q = q_ref[:].astype(jnp.float32) * scale              # [1, hd]
+    q = q_ref[0].astype(jnp.float32) * scale              # [1, hd]
     hd = q_ref.shape[-1]
     col = jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
 
@@ -97,6 +102,7 @@ def _decode_kernel(seq_ref, pt_ref, q_ref, kp_ref, vp_ref, o_ref, *,
         kb = kp_ref[0, phys].astype(jnp.float32)          # [ps, hd]
         vb = vp_ref[0, phys].astype(jnp.float32)
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                                precision=_F32,
                                 preferred_element_type=jnp.float32)
         k_idx = j * page_size + col                        # [1, ps]
         valid = k_idx < kv_len
@@ -108,7 +114,7 @@ def _decode_kernel(seq_ref, pt_ref, q_ref, kp_ref, vp_ref, o_ref, *,
         p = jnp.exp(s - m_new[:, None])
         l_new = l * corr + p.sum(axis=-1)
         acc_new = acc * corr[:, None] + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
+            p, vb, (((1,), (0,)), ((), ())), precision=_F32,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
@@ -121,14 +127,14 @@ def _decode_kernel(seq_ref, pt_ref, q_ref, kp_ref, vp_ref, o_ref, *,
     lo = 0 if window is None else jnp.maximum(
         0, (kv_len - window) // page_size)
     m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, a0))
-    o_ref[:] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
 
 
 def flash_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                        v_pages: jnp.ndarray, page_table: jnp.ndarray,
                        kv_len, *, window: Optional[int] = None,
                        scale: Optional[float] = None,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: Optional[bool] = None) -> jnp.ndarray:
     """Decode-step (``q_len == 1``) flash attention over a paged KV cache.
 
     ``q``: [BH, hd]; ``k_pages``/``v_pages``: [BH, n_phys_pages, page_size,
@@ -143,34 +149,36 @@ def flash_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     kernel = functools.partial(_decode_kernel, scale=scale, window=window,
                                page_size=page_size)
-    from jax.experimental.pallas import tpu as pltpu
+    # q and the output travel as [BH, 1, hd] so each step's (1, hd) block
+    # spans the array's last two dims, as the TPU's (8, 128) tiling needs
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(BH,),
         in_specs=[
-            pl.BlockSpec((1, hd), lambda b, seq, pt: (b, 0)),
+            pl.BlockSpec((1, 1, hd), lambda b, seq, pt: (b, 0, 0)),
             pl.BlockSpec((1, n_pages, page_size, hd),
                          lambda b, seq, pt: (b, 0, 0, 0)),
             pl.BlockSpec((1, n_pages, page_size, hd),
                          lambda b, seq, pt: (b, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, hd), lambda b, seq, pt: (b, 0)),
+        out_specs=pl.BlockSpec((1, 1, hd), lambda b, seq, pt: (b, 0, 0)),
     )
     seq = jnp.asarray([kv_len], jnp.int32)
     pt = jnp.asarray(page_table, jnp.int32)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((BH, hd), q.dtype),
-        interpret=interpret,
-    )(seq, pt, q, k_pages, v_pages)
+        out_shape=jax.ShapeDtypeStruct((BH, 1, hd), q.dtype),
+        interpret=interpret_mode(interpret),
+    )(seq, pt, q.reshape(BH, 1, hd), k_pages, v_pages)
+    return out.reshape(BH, hd)
 
 
 def flash_attention_bh(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                        causal: bool = True, window: Optional[int] = None,
                        scale: Optional[float] = None, block_q: int = 128,
                        block_k: int = 128,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: Optional[bool] = None) -> jnp.ndarray:
     """q/k/v: [BH, S, hd]; S must be a multiple of the block sizes (the
     public wrapper in ops.py pads)."""
     BH, S, hd = q.shape
@@ -190,5 +198,5 @@ def flash_attention_bh(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((1, block_q, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

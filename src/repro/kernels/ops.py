@@ -5,8 +5,9 @@ pads the sequence to block multiples and dispatches to the kernel;
 ``conv2d`` / ``dwconv2d`` route through the shard kernel for any supported
 geometry (stride >= 1, square kernel, non-degenerate output) with an
 automatic XLA fallback otherwise; ``matmul`` is the row-tiled MXU kernel
-behind the engine's FC layers.  ``interpret=True`` everywhere in this
-container (CPU); on a TPU deployment the same calls compile natively.
+behind the engine's FC layers.  ``interpret=None`` (the default) picks
+interpret mode everywhere except a TPU backend
+(:func:`repro.kernels.mode.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -20,6 +21,7 @@ from jax.experimental import pallas as pl
 
 from .conv2d import UnsupportedGeometry, conv2d_shard
 from .flash_attention import flash_attention_bh
+from .mode import interpret_mode
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -27,7 +29,7 @@ from .flash_attention import flash_attention_bh
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: Optional[int] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """q: [B, H, S, hd]; k/v: [B, KV, S, hd] with H % KV == 0."""
     B, H, S, hd = q.shape
     KV = k.shape[1]
@@ -57,12 +59,12 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 def _matmul_kernel(x_ref, w_ref, o_ref):
     o_ref[...] = jax.lax.dot_general(
         x_ref[...].astype(jnp.float32), w_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def matmul_tiled(x: jnp.ndarray, w: jnp.ndarray, *, tile_m: int = 128,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """x: [M, Cin] @ w: [Cin, Cout], output rows tiled by ``tile_m`` (each
     tile is one MXU matmul; rows pad to the tile multiple and are dropped
     on return).  Engine FC shards are [seq, Cin] with Cin/Cout possibly
@@ -84,14 +86,14 @@ def matmul_tiled(x: jnp.ndarray, w: jnp.ndarray, *, tile_m: int = 128,
         ],
         out_specs=pl.BlockSpec((tile_m, cout), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nt * tile_m, cout), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp, w)
     return out[:M]
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "interpret"))
 def matmul(x: jnp.ndarray, w: jnp.ndarray, *, tile_m: int = 128,
-           interpret: bool = True) -> jnp.ndarray:
+           interpret: Optional[bool] = None) -> jnp.ndarray:
     """Jit'd :func:`matmul_tiled` with XLA fallback on degenerate shapes."""
     try:
         return matmul_tiled(x, w, tile_m=tile_m, interpret=interpret)
@@ -117,7 +119,7 @@ def _conv_xla(x: jnp.ndarray, w: jnp.ndarray, *, padding: int, stride: int,
                                              "interpret"))
 def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, padding: int = 0,
            stride: int = 1, tile_h: int = 8,
-           interpret: bool = True) -> jnp.ndarray:
+           interpret: Optional[bool] = None) -> jnp.ndarray:
     """x: [H, W, Cin]; w: [K, K, Cin, Cout]; any stride.  Pallas path for
     every non-degenerate square-kernel geometry; degenerate outputs
     (``out_h/out_w <= 0``) fall back to XLA cleanly."""
@@ -132,7 +134,7 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, *, padding: int = 0,
                                              "interpret"))
 def dwconv2d(x: jnp.ndarray, w: jnp.ndarray, *, padding: int = 0,
              stride: int = 1, tile_h: int = 8,
-             interpret: bool = True) -> jnp.ndarray:
+             interpret: Optional[bool] = None) -> jnp.ndarray:
     """Depthwise conv: x [H, W, C]; w [K, K, 1, C] (engine layout)."""
     try:
         return conv2d_shard(x, w, pads=(padding,) * 4, stride=stride,

@@ -44,7 +44,11 @@ def _grid(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
         raise RuntimeError(
             f"mesh {axes}={shape} needs {n} devices, found {len(devs)} "
             f"({hint})")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    # the executors place data with shard_map and plain jit, which is what
+    # Auto axes give; make_mesh's default (Explicit) axes refuse host-side
+    # indexing of arrays sharded over them
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, auto, devices=devs[:n])
 
 
 def make_nodes_mesh(nodes: int, devices: Optional[Sequence] = None):

@@ -215,8 +215,7 @@ def _paged_attn(q, kp, vp, table, kv_len, *, scale, backend):
     0.0 to the softmax sums, so padding never perturbs live outputs."""
     lh, _, _, hd = kp.shape
     if backend == "pallas":
-        return flash_decode_paged(q, kp, vp, table, kv_len, scale=scale,
-                                  interpret=True)
+        return flash_decode_paged(q, kp, vp, table, kv_len, scale=scale)
     k = kp[:, table].reshape(lh, -1, hd)              # logical order
     v = vp[:, table].reshape(lh, -1, hd)
     s = jnp.einsum("hd,htd->ht", q, k) * scale
@@ -407,7 +406,6 @@ class DecodeSession:
         return kps, vps
 
     def _build_mesh_step(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         spec, nodes = self.spec, self.nodes
         H, hd, d, dff = spec.n_heads, spec.head_dim, spec.d_model, spec.d_ff
@@ -489,11 +487,11 @@ class DecodeSession:
                 x = x + hv @ rep["w2"][i]
             return x, nk, nv
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=self._mesh,
             in_specs=(P(), P(), P(), P(AXIS), P(AXIS), P(AXIS)),
             out_specs=(P(), P(AXIS), P(AXIS)),
-            check_rep=False))
+            check_vma=False))
 
     def _mesh_step(self, x, pos):
         kps, vps = self._mesh_pools

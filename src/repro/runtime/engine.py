@@ -52,11 +52,6 @@ BACKENDS = ("xla", "pallas")
 EXECUTORS = ("local", "mesh")
 
 
-def _pallas_interpret() -> bool:
-    """Interpret-mode Pallas everywhere except a real TPU backend."""
-    return jax.default_backend() != "tpu"
-
-
 # ---------------------------------------------------------------------------
 # Reference (unpartitioned) inference
 # ---------------------------------------------------------------------------
@@ -403,10 +398,9 @@ def _apply_record_pallas(rec: _SegRec, w, x: jnp.ndarray) -> jnp.ndarray:
     to the XLA record path."""
     conv_t, k, s, pads, sl, chans = rec
     conv_t = ConvT(conv_t)
-    interp = _pallas_interpret()
     if conv_t == ConvT.FC:
         seg = x.reshape(x.shape[0], x.shape[-1])
-        out = matmul_tiled(seg, w[:, chans[0]:chans[1]], interpret=interp)
+        out = matmul_tiled(seg, w[:, chans[0]:chans[1]])
         return out.reshape(x.shape[0], 1, chans[1] - chans[0])
     if conv_t in (ConvT.ADD, ConvT.CONCAT):
         return x[:, :, chans[0]:chans[1]]
@@ -417,23 +411,31 @@ def _apply_record_pallas(rec: _SegRec, w, x: jnp.ndarray) -> jnp.ndarray:
     xs = x[r0:r1, c0:c1, :]
     if conv_t == ConvT.DWCONV:
         out = conv2d_shard(xs, w, pads=(pt, pb, pl_, pr), stride=s,
-                           depthwise=True, interpret=interp)
+                           depthwise=True)
         return out[:, :, chans[0]:chans[1]]
     wsel = w[:, :, :, chans[0]:chans[1]]
-    return conv2d_shard(xs, wsel, pads=(pt, pb, pl_, pr), stride=s,
-                        interpret=interp)
+    return conv2d_shard(xs, wsel, pads=(pt, pb, pl_, pr), stride=s)
 
 
-def _apply_record_b(rec: _SegRec, w, x: jnp.ndarray,
-                    backend: str) -> jnp.ndarray:
+def _apply_record_b(rec: _SegRec, w, x: jnp.ndarray, backend: str,
+                    log: Optional[List[Optional[str]]] = None
+                    ) -> jnp.ndarray:
     """Backend dispatch for one record.  Geometry support is static (shapes
     are known at trace time), so the pallas->xla fallback resolves during
-    tracing and costs nothing at run time."""
+    tracing and costs nothing at run time.  Only the geometry checks that
+    run before a kernel is built can divert a record to XLA; a kernel the
+    compiler refuses fails the run.  ``log`` (pallas backend) receives
+    ``None`` per record lowered to Pallas, else the reason it runs on XLA."""
     if backend == "pallas":
         try:
-            return _apply_record_pallas(rec, w, x)
-        except UnsupportedGeometry:
-            pass
+            out = _apply_record_pallas(rec, w, x)
+        except UnsupportedGeometry as exc:
+            if log is not None:
+                log.append(str(exc))
+        else:
+            if log is not None:
+                log.append(None)
+            return out
     return _apply_record(rec, w, x)
 
 
@@ -457,6 +459,54 @@ def segment_cache_info():
 
 def clear_segment_cache() -> None:
     _compiled_segment.cache_clear()
+
+
+def chain_runs(graph: ModelGraph) -> List[List[int]]:
+    """The layer-id runs both executors execute as chains of segments:
+    the whole graph for a chain, else each linearized branch minus a
+    merge head (merges run in ``merge_tensors``)."""
+    if graph.is_chain:
+        return [list(range(len(graph)))]
+    runs = []
+    for br in graph.linearize():
+        ids = list(br.ids)
+        rest = ids[1:] if len(graph.producer_ids[ids[0]]) >= 2 else ids
+        if rest:
+            runs.append(rest)
+    return runs
+
+
+def pallas_lowering(graph: ModelGraph, weights, plan: Plan,
+                    nodes: int) -> List[Tuple[str, ConvT, Optional[str]]]:
+    """``(layer name, conv type, reason)`` for every segment-layer record
+    the pallas backend runs for ``plan`` on ``nodes`` nodes, one per
+    cell: ``reason`` is None when the record runs on a Pallas kernel (or
+    is an ADD/CONCAT channel pass-through), else why it runs on XLA.
+    Decided by tracing the records abstractly — nothing is compiled."""
+    out: List[Tuple[str, ConvT, Optional[str]]] = []
+    for ids in chain_runs(graph):
+        layers = [graph.layers[i] for i in ids]
+        ws = tuple(weights[i] for i in ids)
+        steps = [plan.steps[i] for i in ids]
+        for (a, b) in steps_segments(steps):
+            for cells in exact_regions(layers[b], steps[a][0], nodes):
+                for reg in cells:
+                    need, in_rect = backward_chain(layers, a, b, reg)
+                    recs = _segment_records(layers, a, b, need, in_rect)
+                    (r, c, ch) = in_rect
+                    x = jax.ShapeDtypeStruct(
+                        (r[1] - r[0], c[1] - c[0], ch[1] - ch[0]),
+                        jnp.float32)
+                    log: List[Optional[str]] = []
+
+                    def run(x, w, recs=recs, log=log):
+                        for rec, wi in zip(recs, w):
+                            x = _apply_record_b(rec, wi, x, "pallas", log)
+                        return x
+                    jax.eval_shape(run, x, ws[a:b + 1])
+                    out.extend((layers[li].name, layers[li].conv_t, why)
+                               for li, why in zip(range(a, b + 1), log))
+    return out
 
 
 def _run_branch(layers: Sequence[LayerSpec],

@@ -58,7 +58,6 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.graph import LayerSpec, ModelGraph
@@ -269,8 +268,8 @@ class _MeshRun:
         (degenerate plans bypass collectives entirely)."""
         if self.n == 1:
             return jax.jit(fn)
-        return jax.jit(shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False))
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
 
     # -- dispatch + instrumentation ---------------------------------------
 

@@ -115,8 +115,10 @@ class Session:
             try:
                 self._mesh = make_nodes_mesh(nodes)
             except RuntimeError:
-                # too few devices: leave the mesh unset so the executor's
-                # fallback policy decides (degrade-to-local vs raise)
+                # too few devices: only the degrade-to-local policy may
+                # run without the mesh; every other policy fails here
+                if config.fallback != "local":
+                    raise
                 self._mesh = None
 
     @property

@@ -324,11 +324,18 @@ def test_serve_decode_continuous_batching():
 # mesh executor (subprocess, 8 fake devices)
 # ---------------------------------------------------------------------------
 
+#: mesh decode vs the oracle, as max|logits - ref| / max|ref|: both sides
+#: run f32 on the CPU, and sharding heads and d_ff only reorders the sums
+#: of those contractions (~1e-6 relative); 1e-4 leaves 100x headroom
+MESH_LOGITS_TOL = 1e-4
+
+
 @pytest.mark.slow
 def test_decode_mesh_equivalence():
-    """Token-for-token identical on a real device mesh at nodes 2/4/8
-    (xla backend) plus a pallas spot-check — searched plans only."""
-    code = textwrap.dedent("""
+    """Logits within ``MESH_LOGITS_TOL`` of the oracle, and hence the same
+    greedy tokens, on a real device mesh at nodes 2/4/8 (xla backend)
+    plus a pallas spot-check — searched plans only."""
+    code = f"TOL = {MESH_LOGITS_TOL!r}\n" + textwrap.dedent("""
         import numpy as np, jax
         assert len(jax.devices()) == 8, jax.devices()
         from repro.core.cost import Testbed
@@ -354,11 +361,12 @@ def test_decode_mesh_equivalence():
                                             backend=backend),
                                  page_size=4, capacity=32)
             toks, lg = greedy_decode(sess, prompt, n_new)
+            ref = np.asarray(ref_lg)
+            err = float(np.max(np.abs(np.asarray(lg) - ref)
+                              / np.max(np.abs(ref))))
+            assert err < TOL, (nodes, backend, err)
             assert toks == ref_toks, (nodes, backend, toks)
-            err = float(np.max(np.abs(np.asarray(lg) -
-                                      np.asarray(ref_lg))))
-            assert err < 1e-3, (nodes, backend, err)
-            print("MESH_DECODE_OK", nodes, backend)
+            print("MESH_DECODE_OK", nodes, backend, err)
         print("ALL_MESH_DECODE_OK")
     """)
     env = dict(os.environ)

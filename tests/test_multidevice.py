@@ -120,7 +120,6 @@ def test_shard_map_cnn_halo_exchange():
         import numpy as np
         from functools import partial
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         devs = jax.devices()[:4]
         mesh = jax.make_mesh((4,), ('rows',), devices=devs)
@@ -134,7 +133,7 @@ def test_shard_map_cnn_halo_exchange():
                 x[None], w, (1, 1), [(1, 1), (1, 1)],
                 dimension_numbers=('NHWC', 'HWIO', 'NHWC'))[0]
 
-        @partial(shard_map, mesh=mesh, in_specs=(P('rows', None, None),),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P('rows', None, None),),
                  out_specs=P('rows', None, None))
         def sharded_conv(xs):
             # halo exchange: one boundary row from each neighbour
