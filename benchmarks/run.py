@@ -26,7 +26,7 @@ def main(argv: list[str] | None = None) -> None:
     from . import (churn_bench, decode_bench, engine_comm,
                    estimator_quality, fig2_microbench,
                    fig7_fig9_comparison, fig8_score, kernel_bench,
-                   mesh_bench, roofline_table, search_time, sweep, tpu_ce)
+                   mesh_bench, search_time, sweep)
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     print("name,us_per_call,derived")
@@ -54,8 +54,6 @@ def main(argv: list[str] | None = None) -> None:
     # data-driven CE: small trace budget by default (full 330K via
     # benchmarks.estimator_quality --full)
     estimator_quality.run(n_samples=8_000, trees=40)
-    roofline_table.run()
-    tpu_ce.run()
 
 
 if __name__ == "__main__":

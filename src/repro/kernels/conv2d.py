@@ -154,6 +154,7 @@ def conv2d_shard(x: jnp.ndarray, w: jnp.ndarray, *, pads: Pads = (0, 0, 0, 0),
         out_specs=pl.BlockSpec((tile_h, out_w, cout), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nt * tile_h, out_w, cout), x.dtype),
         interpret=interpret_mode(interpret),
+        name="conv2d_shard",
     )(xph, w)
     return out[:out_h]
 

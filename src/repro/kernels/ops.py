@@ -87,6 +87,7 @@ def matmul_tiled(x: jnp.ndarray, w: jnp.ndarray, *, tile_m: int = 128,
         out_specs=pl.BlockSpec((tile_m, cout), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nt * tile_m, cout), x.dtype),
         interpret=interpret_mode(interpret),
+        name="matmul_tiled",
     )(xp, w)
     return out[:M]
 

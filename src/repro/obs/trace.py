@@ -18,6 +18,14 @@ JSON schema (``ph``/``ts``/``pid``/``tid``/``name`` — load the file at
 https://ui.perfetto.dev).  The same schema is used for the *simulated*
 timeline (``cluster.simsched.export_sim_trace``), so a measured mesh
 trace and its prediction diff structurally (``obs.skew``).
+
+A tracer has one *sink*, where its spans go.  ``"perfetto"`` (the
+default) records them as above.  ``"profiler"`` opens a
+``jax.profiler.TraceAnnotation`` per span instead: inside a
+``jax.profiler`` trace the span lands on the profiler's host plane, on
+the clock of the device's ops, with its args as the event's stats.  That
+sink keeps no records; an externally timed span (``add_complete``) has
+no place on the profiler's clock and is dropped.
 """
 from __future__ import annotations
 
@@ -29,6 +37,9 @@ from typing import Any, Dict, List, Optional, Tuple
 #: canonical track names (Perfetto thread rows)
 PLANNER_TRACK = "planner"
 CONTROL_TRACK = "control"
+
+#: where a :class:`Tracer`'s spans go
+SINKS = ("perfetto", "profiler")
 
 #: span categories with gate semantics: ``cat="stage"`` spans on the
 #: control track are the ones contracted to match
@@ -104,6 +115,23 @@ class Span:
         self._tracer.instant(self.track, name, **args)
 
 
+def _profiler_span_type():
+    """The span type of the ``"profiler"`` sink: a
+    ``jax.profiler.TraceAnnotation`` with the :class:`Span` API, whose
+    ``set(**args)`` adds to the event's stats.  Opening and closing one
+    runs no Python, which the profiler's Python tracer would record."""
+    from jax.profiler import TraceAnnotation
+
+    class ProfilerSpan(TraceAnnotation):
+        set = TraceAnnotation.set_metadata
+
+        def event(self, name: str, **args) -> None:
+            with TraceAnnotation(name, **args):
+                pass
+
+    return ProfilerSpan
+
+
 class Tracer:
     """Collects span/instant records; thread safe; exports Perfetto
     trace-event JSON via :meth:`to_perfetto` / :func:`write_trace`.
@@ -111,9 +139,18 @@ class Tracer:
     ``pid``/``process`` name the Perfetto process row — measured traces
     use ``(1, "measured")``, simulated timelines ``(2, "simulated")``,
     so both fit in one file and line up vertically.
+
+    ``sink="profiler"`` sends every span to the ``jax.profiler`` trace
+    instead (module docstring); ``jax`` is imported only then.
     """
 
-    def __init__(self, process: str = "measured", pid: int = 1) -> None:
+    def __init__(self, process: str = "measured", pid: int = 1,
+                 sink: str = "perfetto") -> None:
+        if sink not in SINKS:
+            raise ValueError(f"sink {sink!r} not in {SINKS}")
+        self.sink = sink
+        self._profiler_span = (_profiler_span_type()
+                               if sink == "profiler" else None)
         self.process = process
         self.pid = pid
         self._epoch = time.perf_counter()
@@ -139,12 +176,19 @@ class Tracer:
                 self._tracks[track] = tid
             return tid
 
-    def span(self, track: str, name: str, cat: str = "span",
-             **args) -> Span:
+    def span(self, track: str, name: str, cat: str = "span", **args):
+        """A :class:`Span`; under the profiler sink, which has no tracks
+        or categories, an annotation named ``name`` with ``args`` as its
+        stats."""
+        if self._profiler_span is not None:
+            return self._profiler_span(name, **args)
         return Span(self, track, name, cat, args)
 
     def instant(self, track: str, name: str, cat: str = "event",
                 **args) -> None:
+        if self._profiler_span is not None:
+            with self._profiler_span(name, **args):
+                return
         self.ensure_track(track)
         rec = {"ph": "i", "track": track, "name": name, "cat": cat,
                "ts": self.now_us(), "args": args}
@@ -155,7 +199,10 @@ class Tracer:
                      dur_us: float, cat: str = "span", depth: int = 0,
                      args: Optional[Dict[str, Any]] = None) -> None:
         """Record an externally-timed complete span (e.g. a mesh stage
-        whose wall time was measured by the executor itself)."""
+        whose wall time was measured by the executor itself); dropped by
+        the profiler sink."""
+        if self._profiler_span is not None:
+            return
         self.ensure_track(track)
         rec = {"ph": "X", "track": track, "name": name, "cat": cat,
                "ts": float(t0_us), "dur": float(dur_us), "depth": depth,

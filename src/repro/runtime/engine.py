@@ -257,6 +257,12 @@ class ExecStats:
     timeouts: int = dataclasses.field(default=0, compare=False)
     #: runs completed by the degraded single-process fallback
     fallbacks: int = dataclasses.field(default=0, compare=False)
+    #: stage programs the mesh executor launched (re-attempts included).
+    #: Excluded from equality: a dispatch count, not geometry.
+    launches: int = dataclasses.field(default=0, compare=False)
+    #: stage programs the mesh executor built because its program cache
+    #: lacked them: 0 once every shape of a plan is warm
+    cache_misses: int = dataclasses.field(default=0, compare=False)
 
     @property
     def failure_count(self) -> int:

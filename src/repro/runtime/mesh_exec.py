@@ -44,7 +44,21 @@ Stats contract: geometry accounting (``sync_points`` / ``bytes_received``
 / ``redundant_elems`` / ``compute_stages``) is computed from the same
 backward-chained rects as the local executor and is bit-identical to it;
 measured ``stage_times`` / ``wall_s`` are instrumentation-only fields
-excluded from ``ExecStats`` equality.
+excluded from ``ExecStats`` equality, as are the dispatch counters
+``launches`` and ``cache_misses``.
+
+Executor spans: with a tracer installed (``obs.set_tracer``), a request
+is a ``mesh.request`` span (args ``seq``, ``launches``,
+``cache_misses``) around ``mesh.geometry`` (one per segment and per
+merge: regions, records, accounting), ``mesh.lookup`` (one per stage:
+signature to program, with ``mesh.build`` inside on a cache miss),
+``mesh.launch`` (one per stage outside ``instrument=True``: the host
+enqueue of the jitted program, never blocking) and ``mesh.wait`` (the
+final block).  Under ``Tracer(sink="profiler")`` they share the
+``jax.profiler`` trace's clock with the device's ops, whose XLA modules
+are named by stage kind (``jit_stage_compute``, ``_gather``, ``_halo``,
+``_merge``, ``_reshard``).  With no tracer, each stage and segment pays
+one ``is None`` test.
 
 A 1-node plan degenerates to plain jitted programs on the first device —
 no ``shard_map``, no collectives.
@@ -52,6 +66,7 @@ no ``shard_map``, no collectives.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -76,6 +91,13 @@ AXIS = "nodes"
 
 #: terminal-stage-failure behaviours of ``run_partitioned_mesh``
 FALLBACKS = ("raise", "local")
+
+#: category of the executor's per-request spans (``mesh.request``,
+#: ``mesh.geometry``, ``mesh.lookup``, ``mesh.build``, ``mesh.launch``,
+#: ``mesh.wait``), written only while a tracer is installed
+EXEC_CAT = "exec"
+#: sequence numbers of traced requests (``mesh.request``'s ``seq``)
+_REQUEST_SEQ = itertools.count(1)
 
 
 class StageFailure(RuntimeError):
@@ -206,6 +228,12 @@ class _RowsPlan:
     h_dn: int
 
 
+def _named(fn, name: str):
+    """``fn`` renamed ``name``: ``jax.jit`` names its program after it."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def _run_recs(recs, ws, x, backend: str):
     for rec, w in zip(recs, ws):
         x = _apply_record_b(rec, w, x, backend)
@@ -253,19 +281,40 @@ class _MeshRun:
             self.n > 1 and mesh is not None
             and mesh.devices.flat[0].platform == "cpu")
 
+    # -- executor spans ---------------------------------------------------
+
+    def _span(self, name: str, f, *args, **span_args):
+        """``f(*args)``, inside the executor span ``name`` when a tracer
+        is installed; the off path pays one ``is None`` test."""
+        tr = self.tracer
+        if tr is None:
+            return f(*args)
+        with tr.span(_obs_trace.CONTROL_TRACK, name, cat=EXEC_CAT,
+                     **span_args):
+            return f(*args)
+
     # -- program cache ----------------------------------------------------
 
-    def _cached(self, key: tuple, build):
-        full_key = (self.mesh_key, self.backend, self.n, self.overlap) + key
+    def _cached(self, sig: Callable[[], tuple], build, label: str):
+        full_key = (self.mesh_key, self.backend, self.n, self.overlap) \
+            + sig()
         fn = _PROG_CACHE.get(full_key)
         if fn is None:
-            fn = build()
+            self.stats.cache_misses += 1
+            fn = self._span("mesh.build", build, label=label)
             _PROG_CACHE[full_key] = fn
         return fn
 
-    def _smap(self, fn, in_specs, out_specs):
+    def _lookup(self, label: str, sig: Callable[[], tuple], build):
+        """The stage program of static signature ``sig()`` — from the
+        cache, or from ``build()`` on a miss (``mesh.lookup``)."""
+        return self._span("mesh.lookup", self._cached, sig, build, label)
+
+    def _smap(self, name: str, fn, in_specs, out_specs):
         """jit(shard_map(fn)) over the nodes axis; plain jit at N == 1
-        (degenerate plans bypass collectives entirely)."""
+        (degenerate plans bypass collectives entirely).  The program takes
+        ``name`` (its XLA module is ``jit_<name>``)."""
+        fn = _named(fn, name)
         if self.n == 1:
             return jax.jit(fn)
         return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
@@ -370,9 +419,18 @@ class _MeshRun:
 
     def _execute(self, kind: str, label: str, fn, *args):
         timed = self.stage_timeout_s is not None
+        self.stats.launches += 1
         if not self.instrument:
+            tr = self.tracer
+
             def body():
-                out = fn(*args)
+                if tr is None:
+                    out = fn(*args)
+                else:
+                    # host enqueue only: the block below stays outside
+                    with tr.span(_obs_trace.CONTROL_TRACK, "mesh.launch",
+                                 cat=EXEC_CAT, kind=kind, label=label):
+                        out = fn(*args)
                 # async dispatch returns before the module runs — with a
                 # watchdog armed the stage must block inside it or the
                 # timeout would never observe the execution
@@ -492,15 +550,16 @@ class _MeshRun:
                 return jnp.stack(outs)
             return run
 
-        sig = ("seg2cells", state_kind, entry_meta, pad_shape, cmax,
-               tuple(tuple(ps) for ps in cellprogs))
+        def sig():
+            return ("seg2cells", state_kind, entry_meta, pad_shape, cmax,
+                    tuple(tuple(ps) for ps in cellprogs))
 
         def build():
             branches = [branch(nd) for nd in range(n)]
             if n == 1:
                 def fn1(full, x_rows, u, d, ws):
                     return branches[0](full, x_rows, u, d, ws)[None]
-                return self._smap(fn1, None, None)
+                return self._smap("stage_compute", fn1, None, None)
 
             def fn(full, x_rows, u, d, ws):
                 xr = None if x_rows is None else x_rows[0]
@@ -513,8 +572,8 @@ class _MeshRun:
                     full, xr, uu, dd, ws)
                 return out[None]
             in_specs = (P(), P(AXIS), P(AXIS), P(AXIS), P())
-            return self._smap(fn, in_specs, P(AXIS))
-        prog = self._cached(sig, build)
+            return self._smap("stage_compute", fn, in_specs, P(AXIS))
+        prog = self._lookup(label, sig, build)
         stack = self._dispatch("compute", label, prog, *args, weights_seg)
         cells = tuple(tuple(cp.reg for cp in ps) for ps in cellprogs)
         return _Cells(stack=stack, cells=cells, shape=out_shape)
@@ -590,9 +649,10 @@ class _MeshRun:
                 return (_pad_dim(y, pad_out, axis), send_up, send_dn)
             return run
 
-        sig = ("seg2rows", state_kind, entry_meta, axis, pad_out,
-               rp.ranges, rp.h_up, rp.h_dn, use_overlap,
-               tuple(cellprogs[nd][0] for nd in range(n)))
+        def sig():
+            return ("seg2rows", state_kind, entry_meta, axis, pad_out,
+                    rp.ranges, rp.h_up, rp.h_dn, use_overlap,
+                    tuple(cellprogs[nd][0] for nd in range(n)))
 
         def build():
             branches = [branch(nd) for nd in range(n)]
@@ -618,8 +678,9 @@ class _MeshRun:
                 return (y[None], up_recv[None], dn_recv[None])
             in_specs = (P(), P(AXIS), P(AXIS), P(AXIS), P())
             n_out = 3 if use_overlap else 1
-            return self._smap(fn, in_specs, tuple([P(AXIS)] * n_out))
-        prog = self._cached(sig, build)
+            return self._smap("stage_compute", fn, in_specs,
+                              tuple([P(AXIS)] * n_out))
+        prog = self._lookup(label, sig, build)
         out = self._dispatch("compute", label, prog, *args, weights_seg)
         if use_overlap:
             block, up, dn = out
@@ -635,8 +696,10 @@ class _MeshRun:
         n = self.n
         axis = rp.axis
         pad = block.shape[1 + 0] if axis == 0 else block.shape[2]
-        sig = ("halo_sync", axis, rp.ranges, rp.h_up, rp.h_dn,
-               tuple(block.shape))
+
+        def sig():
+            return ("halo_sync", axis, rp.ranges, rp.h_up, rp.h_dn,
+                    tuple(block.shape))
 
         def build():
             perm_dn = [(i, i + 1) for i in range(n - 1)]
@@ -661,9 +724,10 @@ class _MeshRun:
                 dn_recv = (jax.lax.ppermute(send_up, AXIS, perm_up)
                            if rp.h_dn > 0 else send_up)
                 return up_recv[None], dn_recv[None]
-            return self._smap(fn, (P(AXIS),), (P(AXIS), P(AXIS)))
+            return self._smap("stage_halo", fn, (P(AXIS),),
+                              (P(AXIS), P(AXIS)))
         del pad
-        prog = self._cached(sig, build)
+        prog = self._lookup(label, sig, build)
         return self._dispatch("sync", label, prog, block)
 
     # -- sync stage: cells -> replicated full -----------------------------
@@ -673,7 +737,9 @@ class _MeshRun:
         cells = state.cells
         shape = state.shape
         dtype = self.dtype
-        sig = ("gather", cells, shape, tuple(state.stack.shape))
+
+        def sig():
+            return ("gather", cells, shape, tuple(state.stack.shape))
 
         def build():
             def rebuild(allc):
@@ -689,12 +755,12 @@ class _MeshRun:
                             allc[nd, j, :dr, :dc, :dch])
                 return full
             if n == 1:
-                return jax.jit(rebuild)
+                return self._smap("stage_gather", rebuild, None, None)
 
             def fn(stack):
                 return rebuild(jax.lax.all_gather(stack[0], AXIS))
-            return self._smap(fn, (P(AXIS),), P())
-        prog = self._cached(sig, build)
+            return self._smap("stage_gather", fn, (P(AXIS),), P())
+        prog = self._lookup(label, sig, build)
         return _Full(self._dispatch("sync", label, prog, state.stack))
 
     # -- merge stages ------------------------------------------------------
@@ -719,7 +785,10 @@ class _MeshRun:
                 shapes.append(st.shape)
                 stacks.append(st.stack)
         dtype = self.dtype
-        sig = ("merge", tuple(metas), tuple(shapes))
+        label = f"merge->{l_m.name}"
+
+        def sig():
+            return ("merge", tuple(metas), tuple(shapes))
 
         def build():
             def rebuild(meta, allc):
@@ -760,25 +829,27 @@ class _MeshRun:
                         else:
                             fulls.append(rebuild(meta, next(it)))
                     return tuple(fulls)
-                return jax.jit(fn1)
+                return self._smap("stage_merge", fn1, None, None)
 
             def fn(x_rep, stks):
                 return core(x_rep, stks)
-            return self._smap(fn, (P(), P(AXIS)),
+            return self._smap("stage_merge", fn, (P(), P(AXIS)),
                               tuple([P()] * len(metas)))
-        prog = self._cached(sig, build)
-        fulls = self._dispatch("sync", f"merge->{l_m.name}", prog,
-                               x_full, tuple(stacks))
+        prog = self._lookup(label, sig, build)
+        fulls = self._dispatch("sync", label, prog, x_full, tuple(stacks))
 
-        msig = ("merge_apply", l_m.conv_t, tuple(shapes))
+        mlabel = f"seg[{l_m.name}..{l_m.name}]"
+
+        def msig():
+            return ("merge_apply", l_m.conv_t, tuple(shapes))
 
         def mbuild():
             def fn(fulls_in):
                 return merge_tensors(l_m, list(fulls_in))
-            return jax.jit(fn)
-        mprog = self._cached(msig, mbuild)
-        merged = self._dispatch(
-            "compute", f"seg[{l_m.name}..{l_m.name}]", mprog, fulls)
+            # replicated in and out: plain jit at every node count
+            return jax.jit(_named(fn, "stage_compute"))
+        mprog = self._lookup(mlabel, msig, mbuild)
+        merged = self._dispatch("compute", mlabel, mprog, fulls)
         return _Full(merged)
 
     # -- plumbing ----------------------------------------------------------
@@ -795,41 +866,52 @@ class _MeshRun:
 
     # -- branch execution --------------------------------------------------
 
+    def _segment_geometry(self, layers: Sequence[LayerSpec], steps, segs,
+                          si: int, owned):
+        """Segment ``si``'s static work (``mesh.geometry``): each node's
+        exact output cells with their input rects and record programs,
+        the ``ExecStats`` accounting, and the permute plan of its exit
+        boundary (``None``: gather)."""
+        a, b = segs[si]
+        scheme = steps[a][0]
+        regs_b = exact_regions(layers[b], scheme, self.n)
+        cellprogs: List[List[_CellProg]] = []
+        computed = 0
+        for nd, cells in enumerate(regs_b):
+            ps = []
+            for reg in cells:
+                need, in_rect = backward_chain(layers, a, b, reg)
+                if owned is not None:
+                    held = sum(_rect_elems(_rect_isect(in_rect, o))
+                               for o in owned[nd])
+                    self.stats.bytes_received += DTYPE_BYTES * (
+                        _rect_elems(in_rect) - held)
+                for li in range(a, b):
+                    computed += _rect_elems(need[li])
+                ps.append(_CellProg(
+                    reg, in_rect,
+                    _segment_records(layers, a, b, need, in_rect)))
+            cellprogs.append(ps)
+        self.stats.sync_points += 1
+        self.stats.redundant_elems += float(computed)
+        self.stats.compute_stages += 1
+        rows_plan = None
+        if si + 1 < len(segs):
+            a2, b2 = segs[si + 1]
+            rows_plan = self._permute_plan(scheme, regs_b, layers,
+                                           a2, b2, steps[a2][0])
+        return regs_b, cellprogs, rows_plan
+
     def run_branch(self, layers: Sequence[LayerSpec], weights,
                    steps, state, owned):
         segs = steps_segments(list(steps))
         regs_b = None
         for si, (a, b) in enumerate(segs):
-            scheme = steps[a][0]
             lb = layers[b]
-            regs_b = exact_regions(lb, scheme, self.n)
-            cellprogs: List[List[_CellProg]] = []
-            computed = 0
-            for nd, cells in enumerate(regs_b):
-                ps = []
-                for reg in cells:
-                    need, in_rect = backward_chain(layers, a, b, reg)
-                    if owned is not None:
-                        held = sum(_rect_elems(_rect_isect(in_rect, o))
-                                   for o in owned[nd])
-                        self.stats.bytes_received += DTYPE_BYTES * (
-                            _rect_elems(in_rect) - held)
-                    for li in range(a, b):
-                        computed += _rect_elems(need[li])
-                    ps.append(_CellProg(
-                        reg, in_rect,
-                        _segment_records(layers, a, b, need, in_rect)))
-                cellprogs.append(ps)
-            self.stats.sync_points += 1
-            self.stats.redundant_elems += float(computed)
-            self.stats.compute_stages += 1
+            regs_b, cellprogs, rows_plan = self._span(
+                "mesh.geometry", self._segment_geometry, layers, steps,
+                segs, si, owned)
             label = f"seg[{layers[a].name}..{layers[b].name}]"
-
-            rows_plan = None
-            if si + 1 < len(segs):
-                a2, b2 = segs[si + 1]
-                rows_plan = self._permute_plan(scheme, regs_b, layers,
-                                               a2, b2, steps[a2][0])
             ws = tuple(weights[a:b + 1])
             out_shape = (lb.out_h, lb.out_w, lb.out_c)
             if rows_plan is None:
@@ -926,13 +1008,28 @@ def run_partitioned_mesh(graph: ModelGraph, weights, x: jnp.ndarray,
     run = _MeshRun(graph, mesh, nodes, backend, instrument, overlap,
                    stats, x.dtype, stage_timeout_s, stage_retries,
                    fault_hook)
+    tr = run.tracer
+    if tr is None:
+        return _run_or_degrade(run, graph, weights, x, plan, fallback)
+    with tr.span(_obs_trace.CONTROL_TRACK, "mesh.request", cat=EXEC_CAT,
+                 seq=next(_REQUEST_SEQ)) as sp:
+        try:
+            return _run_or_degrade(run, graph, weights, x, plan, fallback)
+        finally:
+            sp.set(launches=stats.launches,
+                   cache_misses=stats.cache_misses)
+
+
+def _run_or_degrade(run: _MeshRun, graph: ModelGraph, weights, x,
+                    plan: Plan, fallback: str
+                    ) -> Tuple[jnp.ndarray, ExecStats]:
     try:
-        return _mesh_body(run, graph, weights, x, plan, nodes, stats)
+        return _mesh_body(run, graph, weights, x, plan, run.n, run.stats)
     except StageFailure:
         if fallback != "local":
             raise
-        return _run_degraded(graph, weights, x, plan, nodes, backend,
-                             stats)
+        return _run_degraded(graph, weights, x, plan, run.n, run.backend,
+                             run.stats)
 
 
 def _mesh_body(run: _MeshRun, graph: ModelGraph, weights, x, plan: Plan,
@@ -947,7 +1044,7 @@ def _mesh_body(run: _MeshRun, graph: ModelGraph, weights, x, plan: Plan,
         state, _ = run.run_branch(graph.layers, weights, plan.steps,
                                   _Full(x), None)
         out = run._gather_stage("gather", state).arr
-        jax.block_until_ready(out)
+        run._span("mesh.wait", jax.block_until_ready, out)
         stats.wall_s = time.perf_counter() - t0
         return out, stats
 
@@ -962,15 +1059,8 @@ def _mesh_body(run: _MeshRun, graph: ModelGraph, weights, x, plan: Plan,
         prods = graph.producer_ids[head]
         if len(prods) >= 2:
             l_m = layers[head]
-            q = plan.steps[head][0]
-            regs = exact_regions(l_m, q, nodes)
-            stats.sync_points += 1
-            stats.compute_stages += 1
-            stats.bytes_received += _merge_comm_bytes(
-                l_m, prods,
-                [layers[p].out_c if p >= 0 else layers[0].in_c
-                 for p in prods],
-                owned_map, regs)
+            regs = run._span("mesh.geometry", _merge_geometry, graph,
+                             plan, head, nodes, owned_map, stats)
             cur = run._merge_stages(l_m, prods, outs, x)
             owned = regs
             rest = ids[1:]
@@ -1005,9 +1095,26 @@ def _mesh_body(run: _MeshRun, graph: ModelGraph, weights, x, plan: Plan,
             final = run._gather_stage("gather", cur)
     assert final is not None
     out = final.arr
-    jax.block_until_ready(out)
+    run._span("mesh.wait", jax.block_until_ready, out)
     stats.wall_s = time.perf_counter() - t0
     return out, stats
+
+
+def _merge_geometry(graph: ModelGraph, plan: Plan, head: int, nodes: int,
+                    owned_map, stats: ExecStats) -> List[List[Rect]]:
+    """The merge layer ``head``'s regions and its ``ExecStats``
+    accounting (``mesh.geometry``)."""
+    layers = graph.layers
+    l_m = layers[head]
+    prods = graph.producer_ids[head]
+    regs = exact_regions(l_m, plan.steps[head][0], nodes)
+    stats.sync_points += 1
+    stats.compute_stages += 1
+    stats.bytes_received += _merge_comm_bytes(
+        l_m, prods,
+        [layers[p].out_c if p >= 0 else layers[0].in_c for p in prods],
+        owned_map, regs)
+    return regs
 
 
 def _full_to_cells(run: _MeshRun, state: _Full, owned,
@@ -1027,7 +1134,8 @@ def _full_to_cells(run: _MeshRun, state: _Full, owned,
     cmax = max(len(ps) for ps in cells)
     pad_shape = (rm, cm, chm)
     dtype = run.dtype
-    sig = ("reshard", cells, pad_shape, cmax, shape)
+    def sig():
+        return ("reshard", cells, pad_shape, cmax, shape)
 
     def build():
         def branch(nd):
@@ -1041,13 +1149,15 @@ def _full_to_cells(run: _MeshRun, state: _Full, owned,
             return f
         branches = [branch(nd) for nd in range(n)]
         if n == 1:
-            return jax.jit(lambda full: branches[0](full)[None])
+            def fn1(full):
+                return branches[0](full)[None]
+            return run._smap("stage_reshard", fn1, None, None)
 
         def fn(full):
             idx = jax.lax.axis_index(AXIS)
             return jax.lax.switch(idx, branches, full)[None]
-        return run._smap(fn, (P(),), P(AXIS))
-    prog = run._cached(sig, build)
+        return run._smap("stage_reshard", fn, (P(),), P(AXIS))
+    prog = run._lookup("reshard", sig, build)
     stack = run._dispatch("sync", "reshard", prog, state.arr)
     return _Cells(stack=stack, cells=cells, shape=shape)
 

@@ -372,10 +372,12 @@ def test_stage_spans_match_stage_times_one_to_one():
     assert len(tr.spans(cat="device")) == n_dev_expected
 
 
-def test_tracing_disabled_is_bit_identical():
+def test_tracing_disabled_is_bit_identical(monkeypatch):
     """The default (no tracer) and traced runs agree bit-exactly on
     outputs and on the ExecStats geometry contract — instrumentation
-    must never perturb the numerics."""
+    must never perturb the numerics.  That holds for the profiler sink
+    on the uninstrumented path too, with the same dispatch counters; and
+    with no tracer installed nothing is recorded or annotated."""
     from repro.obs import Tracer, get_tracer, set_tracer
 
     assert get_tracer() is None        # tier-1 default: tracing off
@@ -393,6 +395,29 @@ def test_tracing_disabled_is_bit_identical():
     assert s == s_ref
     assert [st.label for st in s.stage_times] == \
         [st.label for st in s_ref.stage_times]
+
+    # profiler sink, uninstrumented: same answer, stats and counters
+    annotated = []
+
+    class Annotation(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            annotated.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    unused = Tracer()
+    ref, s_ref = run_partitioned(g, w, x, plan, nodes=1, executor="mesh")
+    assert annotated == [] and len(unused) == 0
+    set_tracer(Tracer(sink="profiler"))
+    try:
+        out, s = run_partitioned(g, w, x, plan, nodes=1, executor="mesh")
+    finally:
+        set_tracer(None)
+    assert float(jnp.max(jnp.abs(out - ref))) == 0.0
+    assert s == s_ref
+    assert (s.launches, s.cache_misses) == (s_ref.launches,
+                                            s_ref.cache_misses)
+    assert annotated.count("mesh.launch") == s.launches > 0
 
 
 def test_watchdog_timeout_dumps_postmortem(tmp_path):

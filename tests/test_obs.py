@@ -71,6 +71,85 @@ def test_null_span_has_no_instance_dict():
         NULL_SPAN.leak = 1
 
 
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs what the
+    profiler sink does with it."""
+
+    log: list = []
+
+    def __init__(self, name, **args):
+        self.name, self.args = name, dict(args)
+        self.log.append(("new", name))
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+    def set_metadata(self, **args):
+        self.args.update(args)
+        self.log.append(("set", self.name))
+
+
+@pytest.fixture
+def fake_profiler(monkeypatch):
+    """A stand-in ``jax.profiler`` module, so the sink is tested without
+    importing jax."""
+    import sys
+    import types
+    mod = types.ModuleType("jax.profiler")
+    mod.TraceAnnotation = _FakeAnnotation
+    monkeypatch.setitem(sys.modules, "jax.profiler", mod)
+    _FakeAnnotation.log = []
+    return _FakeAnnotation
+
+
+def test_profiler_sink_opens_one_annotation_per_span(fake_profiler):
+    """Each span of the profiler sink enters and exits one
+    TraceAnnotation of its name, nested as the spans are; ``set`` adds
+    stats; instants are empty annotations; nothing is recorded, and an
+    externally timed span is dropped."""
+    tr = Tracer(sink="profiler")
+    assert tr.sink == "profiler"
+    with tr.span(CONTROL_TRACK, "mesh.request", cat="exec", seq=1) as req:
+        with tr.span(CONTROL_TRACK, "mesh.launch", cat="exec",
+                     kind="compute"):
+            pass
+        req.set(launches=1)
+        req.event("marker")
+    tr.add_complete(CONTROL_TRACK, "seg[a..b]", 0.0, 1.0, cat=STAGE_CAT)
+    assert fake_profiler.log == [
+        ("new", "mesh.request"), ("enter", "mesh.request"),
+        ("new", "mesh.launch"), ("enter", "mesh.launch"),
+        ("exit", "mesh.launch"), ("set", "mesh.request"),
+        ("new", "marker"), ("enter", "marker"), ("exit", "marker"),
+        ("exit", "mesh.request")]
+    assert req.args == {"seq": 1, "launches": 1}
+    assert len(tr) == 0 and tr.spans() == []
+
+
+def test_profiler_sink_leaves_the_null_path_alone(fake_profiler):
+    """Built but not installed, a profiler-sink tracer changes nothing:
+    ``span`` still returns the shared NULL_SPAN and opens nothing."""
+    Tracer(sink="profiler")
+    assert span(CONTROL_TRACK, "mesh.launch") is NULL_SPAN
+    assert fake_profiler.log == []
+    set_tracer(Tracer(sink="profiler"))
+    with span(CONTROL_TRACK, "mesh.launch"):
+        pass
+    assert fake_profiler.log == [("new", "mesh.launch"),
+                                 ("enter", "mesh.launch"),
+                                 ("exit", "mesh.launch")]
+
+
+def test_unknown_sink_is_refused():
+    with pytest.raises(ValueError, match="sink"):
+        Tracer(sink="stdout")
+
+
 def test_set_tracer_roundtrip():
     tr = Tracer()
     assert set_tracer(tr) is tr

@@ -75,3 +75,36 @@ def test_flash_decode_paged_compiles(one_chip):
                                                    interpret=False),
              one_chip, (bh, hd), (bh, pages, ps, hd), (bh, pages, ps, hd),
              (pages,), dtypes=[jnp.float32] * 3 + [jnp.int32])
+
+
+def test_kernels_are_named_in_the_stage_program(one_chip):
+    """On the chip each kernel's op is named by its ``pallas_call``
+    (``conv2d_shard.N``, ``matmul_tiled.N``), inside a program named by
+    its stage; the conv op keeps its two 4-D operands, the shape a trace
+    reader tells it from the 2-D matmul by."""
+    import re
+
+    from jax._src.lib import xla_client
+
+    def stage_compute(x, w, x2, w2):
+        y = conv2d_shard(x, w, pads=(1, 1, 1, 1), stride=1,
+                         interpret=False)
+        return y, matmul_tiled(x2, w2, interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in ((56, 56, 64), (3, 3, 64, 64), (1, 2048),
+                      (2048, 1000))]
+    (mod,) = jax.jit(stage_compute).lower(*args).compile() \
+        .runtime_executable().hlo_modules()
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    text = mod.to_string(opts)
+    assert mod.name == "jit_stage_compute"
+    calls = {m.group(1): m.group(2) for m in re.finditer(
+        r"(\w+)\.\d+ = \S+ custom-call\((.*?)\), "
+        r'custom_call_target="tpu_custom_call"', text)}
+    assert sorted(calls) == ["conv2d_shard", "matmul_tiled"]
+    four_d = r"f32\[\d+(?:,\d+){3}\]"
+    assert re.fullmatch(rf"{four_d}\S* \S+, {four_d}\S* \S+",
+                        calls["conv2d_shard"])
+    assert not re.search(four_d, calls["matmul_tiled"])
