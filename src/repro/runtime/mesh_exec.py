@@ -47,18 +47,41 @@ measured ``stage_times`` / ``wall_s`` are instrumentation-only fields
 excluded from ``ExecStats`` equality, as are the dispatch counters
 ``launches`` and ``cache_misses``.
 
+Two paths run a request, chosen by the policy the caller passes:
+
+* **Plan path** (every policy without per-stage semantics, the default):
+  on the first request of a (graph, plan, input shape and dtype), the
+  whole sequence of stages above is traced once into one jitted function of
+  ``(weights, x)`` — the *plan program*, XLA module ``jit_stage_plan``,
+  cached in ``_PROG_CACHE`` beside the stage programs with the
+  ``ExecStats`` geometry its trace recorded.  Every later request
+  launches it once and blocks once: no geometry, no per-stage lookup,
+  no plan validation.  Weights are arguments, never baked-in constants.
+  A launch that raises is a :class:`StageDispatchError` labelled
+  ``"plan"``, so ``fallback="local"`` still degrades.
+* **Staged path** (``instrument=True``, ``stage_timeout_s``,
+  ``stage_retries > 0`` or a ``fault_hook``): each stage is dispatched
+  as its own program, so that it can be timed, watched, retried or
+  faulted, as the stage-decomposition validation and measured occupancy
+  need.
+
 Executor spans: with a tracer installed (``obs.set_tracer``), a request
-is a ``mesh.request`` span (args ``seq``, ``launches``,
-``cache_misses``) around ``mesh.geometry`` (one per segment and per
-merge: regions, records, accounting), ``mesh.lookup`` (one per stage:
-signature to program, with ``mesh.build`` inside on a cache miss),
-``mesh.launch`` (one per stage outside ``instrument=True``: the host
-enqueue of the jitted program, never blocking) and ``mesh.wait`` (the
-final block).  Under ``Tracer(sink="profiler")`` they share the
-``jax.profiler`` trace's clock with the device's ops, whose XLA modules
-are named by stage kind (``jit_stage_compute``, ``_gather``, ``_halo``,
-``_merge``, ``_reshard``).  With no tracer, each stage and segment pays
-one ``is None`` test.
+is a ``mesh.request`` span (args ``seq``, ``path`` — ``"plan"`` or
+``"staged"`` —, ``launches``, ``cache_misses``) around ``mesh.geometry``
+(one per segment and per merge: regions, records, accounting),
+``mesh.lookup`` (one per program: signature to program, with
+``mesh.build`` inside on a cache miss), ``mesh.launch`` (one per
+launched program outside ``instrument=True``: the host enqueue of the
+jitted program, never blocking; arg ``kind`` is ``"plan"`` for a plan
+program) and ``mesh.wait`` (the final block).  A warm plan-path request
+is one lookup, one launch and one wait; on its first request the
+geometry spans lie inside the ``mesh.build`` labelled ``"plan"``, where
+the program is traced.  Under ``Tracer(sink="profiler")`` the spans
+share the ``jax.profiler`` trace's clock with the device's ops, whose
+XLA modules are named by kind (``jit_stage_plan``; on the staged path
+``jit_stage_compute``, ``_gather``, ``_halo``, ``_merge``,
+``_reshard``).  With no tracer, each stage and segment pays one ``is
+None`` test.
 
 A 1-node plan degenerates to plain jitted programs on the first device —
 no ``shard_map``, no collectives.
@@ -250,7 +273,8 @@ class _MeshRun:
                  dtype, stage_timeout_s: Optional[float] = None,
                  stage_retries: int = 0,
                  fault_hook: Optional[Callable[[str, str, int],
-                                               None]] = None) -> None:
+                                               None]] = None,
+                 tracing: bool = False) -> None:
         self.graph = graph
         self.mesh = mesh
         self.n = nodes
@@ -262,6 +286,14 @@ class _MeshRun:
         self.stage_timeout_s = stage_timeout_s
         self.stage_retries = stage_retries
         self.fault_hook = fault_hook
+        #: the run is being traced into a plan program: stages are
+        #: called on tracers, never dispatched on their own
+        self.tracing = tracing
+        #: the policy has per-stage semantics (measured stage times, a
+        #: per-stage watchdog, retries or fault seam), which need the
+        #: stage boundaries: dispatch stage by stage, not one plan program
+        self.staged = (instrument or stage_timeout_s is not None
+                       or stage_retries > 0 or fault_hook is not None)
         # observability: tracer is cached once (None = tracing off, the
         # zero-overhead default); the flight ring is always on — deque
         # appends never touch numerics, so runs stay bit-identical
@@ -276,9 +308,10 @@ class _MeshRun:
         # collective_ops_utils "may be stuck" stalls on deep models).
         # Serialize stage dispatches there; on real accelerator backends
         # per-device FIFO launch order makes async dispatch safe and the
-        # pipeline stays in flight.
+        # pipeline stays in flight.  A plan program is one module per
+        # request, so the plan path never needs it.
         self.serialize = (
-            self.n > 1 and mesh is not None
+            self.n > 1 and mesh is not None and self.staged
             and mesh.devices.flat[0].platform == "cpu")
 
     # -- executor spans ---------------------------------------------------
@@ -333,7 +366,11 @@ class _MeshRun:
 
         Every dispatch rides the flight ring; terminal failures dump a
         postmortem artifact (``obs.flight.dump_postmortem`` — a no-op
-        unless a postmortem directory is configured)."""
+        unless a postmortem directory is configured).
+
+        While a plan program is traced, the stage is only called."""
+        if self.tracing:
+            return fn(*args)
         attempt = 0
         self.flight.record("stage_dispatch", stage_kind=kind,
                            label=label)
@@ -978,7 +1015,11 @@ def run_partitioned_mesh(graph: ModelGraph, weights, x: jnp.ndarray,
     the plan needs (mesh shrink) or a stage fails terminally.
     ``fault_hook(kind, label, attempt)`` is called before every stage
     attempt — a test seam for deterministic fault injection.
-    ``ExecStats.retries/timeouts/fallbacks`` record what happened."""
+    ``ExecStats.retries/timeouts/fallbacks`` record what happened.
+
+    Any of ``instrument``, ``stage_timeout_s``, ``stage_retries`` or
+    ``fault_hook`` keeps the staged path (one program per stage); without
+    them the request is one plan program (module docstring)."""
     if backend not in BACKENDS:
         raise ValueError(f"backend {backend!r} not in {BACKENDS}")
     if nodes < 1:
@@ -1012,7 +1053,8 @@ def run_partitioned_mesh(graph: ModelGraph, weights, x: jnp.ndarray,
     if tr is None:
         return _run_or_degrade(run, graph, weights, x, plan, fallback)
     with tr.span(_obs_trace.CONTROL_TRACK, "mesh.request", cat=EXEC_CAT,
-                 seq=next(_REQUEST_SEQ)) as sp:
+                 seq=next(_REQUEST_SEQ),
+                 path="staged" if run.staged else "plan") as sp:
         try:
             return _run_or_degrade(run, graph, weights, x, plan, fallback)
         finally:
@@ -1023,30 +1065,94 @@ def run_partitioned_mesh(graph: ModelGraph, weights, x: jnp.ndarray,
 def _run_or_degrade(run: _MeshRun, graph: ModelGraph, weights, x,
                     plan: Plan, fallback: str
                     ) -> Tuple[jnp.ndarray, ExecStats]:
+    t0 = time.perf_counter()
     try:
-        return _mesh_body(run, graph, weights, x, plan, run.n, run.stats)
+        if run.staged:
+            out = _mesh_body(run, graph, weights, x, plan)
+        else:
+            out = _plan_launch(run, graph, weights, x, plan)
+        run._span("mesh.wait", jax.block_until_ready, out)
     except StageFailure:
         if fallback != "local":
             raise
         return _run_degraded(graph, weights, x, plan, run.n, run.backend,
                              run.stats)
+    run.stats.wall_s = time.perf_counter() - t0
+    return out, run.stats
 
 
-def _mesh_body(run: _MeshRun, graph: ModelGraph, weights, x, plan: Plan,
-               nodes: int, stats: ExecStats
-               ) -> Tuple[jnp.ndarray, ExecStats]:
-    t0 = time.perf_counter()
+# ---------------------------------------------------------------------------
+# plan programs: a whole request as one jitted program
+# ---------------------------------------------------------------------------
 
+#: the ``ExecStats`` fields a plan program's trace records once for all
+#: of its requests: the geometry accounting
+_GEOMETRY_FIELDS = ("sync_points", "bytes_received", "redundant_elems",
+                    "compute_stages")
+
+
+@dataclasses.dataclass(frozen=True)
+class _PlanProgram:
+    """One request of a (graph, plan, input shape) traced into one
+    jitted function of ``(weights, x)`` (XLA module ``jit_stage_plan``),
+    with the geometry accounting its trace recorded.  Holding ``graph``
+    and ``plan`` keeps their ``id``s, which key the cache, from being
+    reused while the entry lives."""
+
+    graph: ModelGraph
+    plan: Plan
+    fn: Callable
+    stats: ExecStats
+
+
+def _plan_launch(run: _MeshRun, graph: ModelGraph, weights, x,
+                 plan: Plan):
+    """The plan path: look the plan program up (``mesh.lookup``; on a
+    miss trace, lower and compile it inside ``mesh.build``, label
+    ``"plan"``), launch it once (``mesh.launch``, kind ``"plan"``) and
+    copy its recorded geometry into this request's stats.  A launch that
+    raises is a :class:`StageDispatchError` labelled ``"plan"``."""
+
+    def sig():
+        return ("plan", id(graph), id(plan), tuple(x.shape), str(x.dtype))
+
+    def build():
+        box: Dict[str, ExecStats] = {}
+
+        def stage_plan(ws, xx):
+            # runs only while traced: the geometry is recorded once
+            trace_run = _MeshRun(graph, run.mesh, run.n, run.backend,
+                                 False, run.overlap, ExecStats(), xx.dtype,
+                                 tracing=True)
+            out = _mesh_body(trace_run, graph, ws, xx, plan)
+            box["stats"] = trace_run.stats
+            return out
+        fn = jax.jit(stage_plan)
+        fn.lower(weights, x).compile()
+        # stage programs the trace built are this request's misses too
+        run.stats.cache_misses += box["stats"].cache_misses
+        return _PlanProgram(graph, plan, fn, box["stats"])
+
+    prog = run._lookup("plan", sig, build)
+    out = run._dispatch("plan", "plan", prog.fn, weights, x)
+    for f in _GEOMETRY_FIELDS:
+        setattr(run.stats, f, getattr(prog.stats, f))
+    return out
+
+
+def _mesh_body(run: _MeshRun, graph: ModelGraph, weights, x, plan: Plan):
+    """Every stage of one request, in order, from the input to the
+    replicated output: dispatched one by one on the staged path, traced
+    into the plan program on the plan path."""
+    nodes = run.n
+    stats = run.stats
     if graph.is_chain:
         plan.validate()
         if len(plan) != len(graph):
             raise ValueError("plan/graph length mismatch")
         state, _ = run.run_branch(graph.layers, weights, plan.steps,
                                   _Full(x), None)
-        out = run._gather_stage("gather", state).arr
-        run._span("mesh.wait", jax.block_until_ready, out)
-        stats.wall_s = time.perf_counter() - t0
-        return out, stats
+        return run._gather_stage("gather", state).arr
 
     plan.validate_for(graph)
     layers = graph.layers
@@ -1094,10 +1200,7 @@ def _mesh_body(run: _MeshRun, graph: ModelGraph, weights, x, plan: Plan,
         if not graph.consumer_ids[ids[-1]]:
             final = run._gather_stage("gather", cur)
     assert final is not None
-    out = final.arr
-    run._span("mesh.wait", jax.block_until_ready, out)
-    stats.wall_s = time.perf_counter() - t0
-    return out, stats
+    return final.arr
 
 
 def _merge_geometry(graph: ModelGraph, plan: Plan, head: int, nodes: int,

@@ -14,7 +14,17 @@ sprawl into its two actual lifetimes:
   (``engine._compiled_segment``) and mesh program signature
   (``mesh_exec._PROG_CACHE``), so a Session's second ``run`` skips
   retracing entirely; the Session additionally pins the mesh object so
-  repeated mesh runs don't rebuild device layouts.
+  repeated mesh runs don't rebuild device layouts, and places the
+  weights on every device of that mesh once.
+
+The mesh executor runs a request on one of two paths, chosen by the
+policy alone (no field of its own): a policy with per-stage semantics —
+``instrument``, ``stage_timeout_s``, ``stage_retries > 0`` or a
+``fault_hook`` — dispatches each pipeline stage as its own program (the
+staged path); every other policy launches the whole request as one
+jitted program traced from the same stages (the plan path), one launch
+per request once warm.  ``runtime.mesh_exec`` describes both and the
+``mesh.request`` span's ``path`` arg that names the one taken.
 
 ``run_partitioned(**kwargs)`` survives as a thin back-compat shim over
 ``Session`` and warns ``DeprecationWarning``.
@@ -120,6 +130,15 @@ class Session:
                 if config.fallback != "local":
                     raise
                 self._mesh = None
+        #: the weights the executor reads: on a mesh, placed once on
+        #: every device, as the stage programs replicate them, so that no
+        #: request copies them from one device to the others again
+        self._weights = weights
+        if config.executor == "mesh" and self._mesh is not None:
+            import jax
+            from jax.sharding import NamedSharding, PartitionSpec
+            self._weights = jax.device_put(
+                weights, NamedSharding(self._mesh, PartitionSpec()))
 
     @property
     def mesh(self):
@@ -132,7 +151,7 @@ class Session:
         if cfg.executor == "mesh":
             from repro.runtime.mesh_exec import run_partitioned_mesh
             return run_partitioned_mesh(
-                self.graph, self.weights, x, self.plan, self.nodes,
+                self.graph, self._weights, x, self.plan, self.nodes,
                 backend=cfg.backend, mesh=self._mesh,
                 instrument=cfg.instrument, overlap=cfg.overlap,
                 stage_timeout_s=cfg.stage_timeout_s,
