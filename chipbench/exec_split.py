@@ -170,9 +170,9 @@ class ExecSplit:
 def accepted_metrics(tr: Trace, b, cfg: Dict, peak: Dict) -> Dict:
     """The accepted per-layer metrics, read as ``run.py`` reads them."""
     itemsize = work.DTYPE_BYTES[cfg["dtype"]]
-    conv_min_s, _ = work.conv_min_seconds(b.table, itemsize, peak)
+    conv_min_s, _ = work.conv_min_seconds(b.table, itemsize, peak, b.ops)
     ctx = SimpleNamespace(trace=tr, peak=peak, table=b.table,
-                          model_flops=work.model_flops(b.table),
+                          model_flops=work.model_flops(b.table, b.ops),
                           conv_min_s=conv_min_s)
     out = {}
     for name in ACCEPTED:

@@ -9,11 +9,14 @@ on its 3x3 conv (ResNet v1.5), and the final global pool a max pool.
 
 ``layers(cfg)`` is the layer table in serving order; ``forward`` takes
 the weights in that table's order (every conv, then the classifier).
+``model_args`` and ``inputs`` are the image references' own
+(``chipbench/models/image.py``).
 """
 from __future__ import annotations
 
 from typing import Dict, List
 
+from chipbench.models.image import inputs, model_args  # noqa: F401
 from chipbench.work import layer as _layer
 
 

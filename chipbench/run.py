@@ -8,11 +8,24 @@ Run from the root of a checkout, on a machine with the cell's chips:
 Everything a cell needs is found by name.  ``BENCHMARK.json`` names the
 cell's configuration, traffic mix and chips.  The configuration file
 holds the sizes and the plan's node count, one node per chip, and names
-the program's model and the plain reference in
-``chipbench/models/<reference>.py``.  The mix is
-``chipbench/traffic/<mix>.json``, read by ``chipbench/traffic.py``.  The
-limits of the correctness check are ``chipbench/cells/<cell>.json``.
-Each per-layer metric is read by ``chipbench/metrics/<metric>.py``.
+the program's model (``model``, a key of ``EDGE_MODELS``) and the plain
+reference (``reference``), ``chipbench/models/<reference>.py``.  The
+mix is ``chipbench/traffic/<mix>.json``, read by
+``chipbench/traffic.py``.  The limits of the correctness check are
+``chipbench/cells/<cell>.json``.  Each per-layer metric is read by
+``chipbench/metrics/<metric>.py``.
+
+Everything model-specific is in the reference module, which gives:
+
+- ``layers(cfg)``: the layer table (``work.layer`` rows), in the order
+  of the program's graph;
+- ``model_args(cfg)``: the keyword arguments of the graph builder;
+- ``inputs(cfg, rng, n)``: ``n`` request inputs, stacked, from ``rng``;
+- ``forward(cfg, ws, x, precision)``: the answers of a batch of inputs,
+  with the weights of the layers that have any, in table order;
+- optionally ``OPS``, op kinds that ``work.OPS`` lacks (``work.Op``: the
+  weight shapes, FLOP and bytes), and ``IR_OPS``, program layer types
+  that ``IR_OPS`` here lacks, mapped to op kinds.
 
 A run plans the model for one node per chip with the program's own
 planner, makes the weights on the device from the seed, builds a
@@ -30,6 +43,7 @@ cell's chips.
 from __future__ import annotations
 
 import argparse
+import functools
 import importlib
 import json
 import math
@@ -41,7 +55,7 @@ import tempfile
 import time
 from pathlib import Path
 from types import SimpleNamespace
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 ROOT = Path(__file__).resolve().parents[1]
 for _p in (ROOT, ROOT / "src"):
@@ -56,11 +70,11 @@ BENCH = ROOT / "chipbench"
 TRACE_SECONDS = 4.0
 #: untimed requests after the first, which compiles or loads every program
 WARMUP_REQUESTS = 2
-#: images per call of the jitted reference
+#: inputs per call of the jitted reference
 REF_BLOCK = 8
-#: the program's layer types, as the reference tables name them
-OPS = {"CONV": "conv", "POINTWISE": "conv", "DWCONV": "dwconv",
-       "POOL": "pool", "ADD": "add", "FC": "fc"}
+#: the program's layer types, as the op kinds of ``work.OPS``
+IR_OPS = {"CONV": "conv", "POINTWISE": "conv", "DWCONV": "dwconv",
+          "POOL": "pool", "ADD": "add", "FC": "fc"}
 SHAPE_KEYS = ("in_h", "in_w", "in_c", "out_c", "k", "s", "p")
 #: jax.monitoring events counted inside the window
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -114,46 +128,76 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def make_weights(table: List[Dict], seed: int, dtype: str) -> List:
-    """Seeded normal weights, scaled by 1/sqrt(fan-in), in one jitted call
-    on the device; ``None`` for layers without weights."""
+def reference(cfg: Dict):
+    """The configuration's plain reference module."""
+    return importlib.import_module(f"chipbench.models.{cfg['reference']}")
+
+
+def op_tables(ref) -> Tuple[Dict, Dict]:
+    """``work.OPS`` and ``IR_OPS``, each with the reference's own
+    additions; an addition under a name already taken is refused."""
+    out = []
+    for base, key in ((work.OPS, "OPS"), (IR_OPS, "IR_OPS")):
+        extra = getattr(ref, key, {})
+        taken = sorted(base.keys() & extra.keys())
+        if taken:
+            raise BenchError(f"{ref.__name__}.{key} redefines {taken}")
+        out.append({**base, **extra})
+    return out[0], out[1]
+
+
+def make_weights(table: List[Dict], seed: int, dtype: str,
+                 ops: Dict) -> List:
+    """Seeded normal weights, each tensor scaled by 1/sqrt(its fan-in),
+    in one jitted call on the device.  Each layer takes one key of the
+    seed's; a layer of several tensors splits its key, one per tensor.
+    Per layer: ``None`` without weights, the array of a layer of one
+    tensor, the tuple of a layer of several."""
     import jax
     import jax.numpy as jnp
-    shapes = [work.weight_shape(l) for l in table]
+    shapes = [work.weight_shapes(l, ops) for l in table]
+
+    def draw(key, shape):
+        return (jax.random.normal(key, shape, jnp.dtype(dtype))
+                / math.sqrt(math.prod(shape[:-1])))
 
     def init(key):
-        keys = jax.random.split(key, len(shapes))
-        return [jax.random.normal(k, s, jnp.dtype(dtype))
-                / math.sqrt(math.prod(s[:-1]))
-                for k, s in zip(keys, shapes) if s is not None]
+        out = []
+        for k, ss in zip(jax.random.split(key, len(shapes)), shapes):
+            if len(ss) == 1:
+                out.append(draw(k, ss[0]))
+            elif ss:
+                out.append(tuple(map(draw, jax.random.split(k, len(ss)),
+                                     ss)))
+        return out
 
-    flat = iter(jax.jit(init)(seed_key(seed)))
-    return [next(flat) if s is not None else None for s in shapes]
+    drawn = iter(jax.jit(init)(seed_key(seed)))
+    return [next(drawn) if ss else None for ss in shapes]
 
 
-def check_graph(graph, table: List[Dict]) -> None:
+def check_graph(graph, table: List[Dict], ir: Dict = IR_OPS) -> None:
     """The program's layer graph has the reference's layers, in order."""
     if len(graph.layers) != len(table):
         raise BenchError(f"{graph.name}: {len(graph.layers)} layers, the "
                          f"reference has {len(table)}")
     for l, r in zip(graph.layers, table):
-        got = (OPS.get(l.conv_t.name),) + tuple(getattr(l, k)
-                                                 for k in SHAPE_KEYS)
+        got = (ir.get(l.conv_t.name),) + tuple(getattr(l, k)
+                                                for k in SHAPE_KEYS)
         want = (r["op"],) + tuple(r[k] for k in SHAPE_KEYS)
         if got != want:
             raise BenchError(f"{graph.name} layer {l.name}: program "
                              f"{got}, reference {r['name']} {want}")
 
 
-def reference_logits(ref, cfg: Dict, weights: List, pool, images,
+def reference_logits(ref, cfg: Dict, weights: List, pool, items,
                      precision: str) -> Dict[int, object]:
-    """The plain reference's logits for the pool images ``images``, in
+    """The plain reference's answers for the pool inputs ``items``, in
     jitted blocks of ``REF_BLOCK`` on the default device."""
     import jax
     import numpy as np
     ws = [w for w in weights if w is not None]
     fwd = jax.jit(lambda w, x: ref.forward(cfg, w, x, precision))
-    idx = sorted(images)
+    idx = sorted(items)
     out: Dict[int, object] = {}
     for i in range(0, len(idx), REF_BLOCK):
         blk = idx[i:i + REF_BLOCK]
@@ -165,7 +209,7 @@ def reference_logits(ref, cfg: Dict, weights: List, pool, images,
 
 
 def rel_err(answer, ref) -> float:
-    """``max|answer - ref| / max|ref|`` over one image's logits."""
+    """``max|answer - ref| / max|ref|`` over one answer."""
     import numpy as np
     scale = max(float(np.max(np.abs(ref))), 1e-30)
     return float(np.max(np.abs(answer.reshape(ref.shape) - ref))) / scale
@@ -224,9 +268,9 @@ def build(c: SimpleNamespace, seed: int, devices, plan=None
           ) -> SimpleNamespace:
     """Everything a run serves with: the program's graph and plan (the
     planner's own choice, one node per chip, unless ``plan`` is given),
-    the seeded weights and images, and the ``Session`` with its
-    ``serve(image)``.  Call inside ``jax.default_matmul_precision`` of
-    the configuration, as every later call of ``serve``."""
+    the seeded weights and inputs, and the ``Session`` with its
+    ``serve(x)``.  Call inside ``jax.default_matmul_precision`` of the
+    configuration, as every later call of ``serve``."""
     import jax
     import numpy as np
 
@@ -237,10 +281,11 @@ def build(c: SimpleNamespace, seed: int, devices, plan=None
 
     cfg, mix = c.config, c.mix
     chips = c.cell["chips"]
-    ref = importlib.import_module(f"chipbench.models.{cfg['reference']}")
+    ref = reference(cfg)
+    ops, ir = op_tables(ref)
     table = ref.layers(cfg)
-    graph = EDGE_MODELS[cfg["model"]](cfg["image_size"])
-    check_graph(graph, table)
+    graph = EDGE_MODELS[cfg["model"]](**ref.model_args(cfg))
+    check_graph(graph, table, ir)
     if plan is None:
         t = time.perf_counter()
         plan = plan_search(graph, AnalyticEstimator(),
@@ -248,21 +293,21 @@ def build(c: SimpleNamespace, seed: int, devices, plan=None
         _emit({"line": "plan", "nodes": chips, "steps": len(plan.steps),
                "schemes": _schemes(plan),
                "plan_search_s": time.perf_counter() - t})
-    weights = make_weights(table, seed, cfg["dtype"])
-    shape = (cfg["image_size"], cfg["image_size"], cfg["in_channels"])
+    weights = make_weights(table, seed, cfg["dtype"], ops)
     sess = Session(graph, weights, plan, chips,
                    ExecConfig(backend="pallas", executor="mesh",
                               fallback="raise"))
-    b = SimpleNamespace(ref=ref, table=table, graph=graph, plan=plan,
-                        weights=weights, sess=sess, stats=[],
-                        pool=traffic.image_pool(mix, shape, seed),
+    b = SimpleNamespace(ref=ref, ops=ops, table=table, graph=graph,
+                        plan=plan, weights=weights, sess=sess, stats=[],
+                        pool=traffic.pool(mix, seed,
+                                          functools.partial(ref.inputs, cfg)),
                         seq=traffic.order(mix, seed))
 
-    def serve(image):
+    def serve(item):
         with jax.profiler.TraceAnnotation("request"):
             with jax.profiler.TraceAnnotation("put_input"):
                 # uncommitted, so the mesh programs may place it
-                x = jax.device_put(image)
+                x = jax.device_put(item)
             with jax.profiler.TraceAnnotation("session_run"):
                 out, stats = b.sess.run(x)
             with jax.profiler.TraceAnnotation("fetch_output"):
@@ -286,7 +331,7 @@ def warm_up(b: SimpleNamespace) -> None:
 class Control:
     """The correctness control in the program's place: the plain
     reference one precision below the configuration's (``numerics``
-    ``"high"``), one image per call, on the default device.  A run that
+    ``"high"``), one input per call, on the default device.  A run that
     serves it must come out not correct."""
 
     def __init__(self, b: SimpleNamespace, cfg: Dict):
@@ -308,11 +353,11 @@ def answer_errors(c: SimpleNamespace, b: SimpleNamespace, reqs,
     at ``precision`` (the configuration's, unless given)."""
     import jax
     precision = precision or c.config["precision"]
-    images = {r.image for r in reqs if r.error is None}
+    items = {r.item for r in reqs if r.error is None}
     with jax.default_matmul_precision(c.config["precision"]):
         refs = reference_logits(b.ref, c.config, b.weights, b.pool,
-                                images, precision)
-    return [rel_err(r.answer, refs[r.image]) for r in reqs
+                                items, precision)
+    return [rel_err(r.answer, refs[r.item]) for r in reqs
             if r.error is None]
 
 
@@ -368,16 +413,20 @@ def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool,
            "bytes_received_f32": st.bytes_received,
            "redundant_elems": st.redundant_elems,
            "compute_stages": st.compute_stages})
+    lat_ms = sorted(1e3 * r.latency_s for r in ok) or [math.nan]
     _emit({"line": "window", "requests": len(reqs), "failed": failed,
            "compiles_in_window": events.counts[COMPILE_EVENT],
            "traces_in_window": events.counts[TRACE_EVENT],
            "peak_bytes_in_use": mem,
+           # where a rate and a median disagree: the slowest requests
+           "latency_mean_ms": statistics.fmean(lat_ms),
+           "latency_top5_ms": lat_ms[-5:],
            "first_error": next((r.error for r in reqs if r.error), None)})
     _emit(_lowering(b.graph, b.weights, b.plan, chips))
 
     t_ref = time.perf_counter()
     errs = answer_errors(c, b, reqs)
-    _emit({"line": "reference", "images": len({r.image for r in ok}),
+    _emit({"line": "reference", "images": len({r.item for r in ok}),
            "reference_s": time.perf_counter() - t_ref,
            "rel_err_median": statistics.median(errs) if errs else None})
     checks = {
@@ -399,12 +448,13 @@ def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool,
         reduced = reduce_xspace(paths[0], chips)
         shutil.rmtree(tdir, ignore_errors=True)
         tr = Trace(reduced)
-        conv_min_s, bound = work.conv_min_seconds(b.table, itemsize, peak)
-        _emit({"line": "work", **work.summary(b.table, itemsize),
+        conv_min_s, bound = work.conv_min_seconds(b.table, itemsize, peak,
+                                                  b.ops)
+        _emit({"line": "work", **work.summary(b.table, itemsize, b.ops),
                "conv_min_s": conv_min_s, "conv_bound_layers": bound,
                "traced_images": tr.images})
         ctx = SimpleNamespace(trace=tr, peak=peak, table=b.table,
-                              model_flops=work.model_flops(b.table),
+                              model_flops=work.model_flops(b.table, b.ops),
                               conv_min_s=conv_min_s)
         metrics = {}
         for m in per_layer_metrics(c.spec, name):

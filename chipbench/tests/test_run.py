@@ -20,12 +20,16 @@ WIDTH = 32
 PEAKS = {"cpu": {"flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}}
 SEED = 2**31 + 99
 CELLS = ["resnet101_224.chip1.closed1", "mobilenet_v1_224.chip1.closed1",
-         "resnet101_224.mesh4.closed1"]
+         "resnet101_224.mesh4.closed1", "mobilenet_v1_224.mesh4.closed1"]
+#: per cell, the configuration keys that cut it to a CPU size; mobilenet's
+#: four-node plan exchanges halos by ppermute from width 48, not at 32
+SMALL = {CELLS[0]: {"image_size": WIDTH}, CELLS[1]: {"image_size": WIDTH},
+         CELLS[2]: {"image_size": WIDTH}, CELLS[3]: {"image_size": 64}}
 
 
 def small(cell: str):
     c = run.load_cell(cell)
-    c.config["image_size"] = WIDTH
+    c.config.update(SMALL[cell])
     c.mix["image_pool"] = 4
     return c
 
@@ -85,18 +89,27 @@ def _raises(orig):
     return run_
 
 
+@pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("fault", [_stale, _altered, _raises])
-def test_fault_in_the_answer_is_caught(monkeypatch, fault):
+def test_fault_in_the_answer_is_caught(monkeypatch, fault, cell):
     monkeypatch.setattr(Session, "run", fault(Session.run))
-    res = run_small(CELLS[0])
+    res = run_small(cell)
+    assert res["attempted"] >= 2
     assert res["correct"] is False
 
 
-def test_exchange_left_out_is_caught(monkeypatch, fresh_programs):
+@pytest.mark.parametrize("cell", CELLS[2:])
+def test_exchange_left_out_is_caught(monkeypatch, fresh_programs, cell):
     """The halo exchange between chips returns zeros."""
-    monkeypatch.setattr(jax.lax, "ppermute",
-                        lambda x, axis_name, perm: jnp.zeros_like(x))
-    res = run_small(CELLS[2])
+    calls = []
+
+    def left_out(x, axis_name, perm):
+        calls.append(x.shape)
+        return jnp.zeros_like(x)
+
+    monkeypatch.setattr(jax.lax, "ppermute", left_out)
+    res = run_small(cell)
+    assert calls
     assert res["correct"] is False
     assert res["checks"]["rel_err"]["value"] > \
         res["checks"]["rel_err"]["limit"]
@@ -164,6 +177,7 @@ def test_metric_selection_follows_benchmark_json():
              for c in CELLS}
     assert "collective_ms" not in names[CELLS[0]]
     assert "collective_ms" in names[CELLS[2]]
+    assert "collective_ms" in names[CELLS[3]]
     assert "conv_roofline" in names[CELLS[1]]
     with pytest.raises(run.BenchError, match="no workload"):
         run.load_cell("no.such.cell")

@@ -14,6 +14,7 @@ PINNED = [
     ("resnet101_224", resnet, 140, 44_442_816, 15_613_648_896),
     ("resnet101_224_mesh4", resnet, 140, 44_442_816, 15_613_648_896),
     ("mobilenet_v1_224", mobilenet_v1, 29, 4_209_088, 1_137_530_880),
+    ("mobilenet_v1_224_mesh4", mobilenet_v1, 29, 4_209_088, 1_137_530_880),
 ]
 
 
@@ -32,7 +33,7 @@ def test_program_graph_matches_reference(name, ref, n_layers, n_weights,
                                          n_flops):
     from repro.configs.edge_models import EDGE_MODELS
     cfg = json.loads((CONFIGS / f"{name}.json").read_text())
-    graph = EDGE_MODELS[cfg["model"]](cfg["image_size"])
+    graph = EDGE_MODELS[cfg["model"]](**ref.model_args(cfg))
     run.check_graph(graph, ref.layers(cfg))
     assert sum(l.flops() for l in graph.layers) == n_flops
 

@@ -1,5 +1,5 @@
 """The one traffic generator: it reads a mix's parameters from
-``chipbench/traffic/<mix>.json`` and drives a ``serve(image)`` callable.
+``chipbench/traffic/<mix>.json`` and drives a ``serve(x)`` callable.
 
 Parameters a mix file gives:
 
@@ -7,9 +7,10 @@ Parameters a mix file gives:
   request when the answer to the previous one is on the host.  A request
   is due when the previous one completes, so its latency runs from then.
 - ``clients``: how many clients; the loop runs one.
-- ``image_pool``: how many distinct images are drawn from the seed before
-  the window.  Requests take them in an order drawn from the same seed,
-  round and round; the compute does not depend on the values.
+- ``image_pool``: how many distinct inputs are drawn from the seed before
+  the window, by the configuration's reference (``inputs``).  Requests
+  take them in an order drawn from the same seed, round and round; the
+  compute does not depend on the values.
 
 Every seed gives the same number of requests of the same size in a
 window, so a seed changes the values and their order, never the work.
@@ -37,21 +38,20 @@ def load(name: str) -> Dict:
     return mix
 
 
-def image_pool(mix: Dict, shape, seed: int) -> np.ndarray:
-    """``[image_pool, *shape]`` float32 standard-normal images."""
-    rng = np.random.default_rng([seed, 1])
-    return rng.standard_normal((mix["image_pool"], *shape),
-                               dtype=np.float32)
+def pool(mix: Dict, seed: int, draw: Callable) -> np.ndarray:
+    """The mix's inputs, ``draw(rng, n)``: ``n`` inputs, stacked, from a
+    generator seeded apart from the order's."""
+    return draw(np.random.default_rng([seed, 1]), mix["image_pool"])
 
 
 def order(mix: Dict, seed: int) -> np.ndarray:
-    """The order in which requests take the pool's images."""
+    """The order in which requests take the pool's inputs."""
     return np.random.default_rng([seed, 2]).permutation(mix["image_pool"])
 
 
 @dataclasses.dataclass
 class Request:
-    image: int            # index into the pool
+    item: int             # index into the pool
     due_s: float          # host clock when the request was due
     done_s: float         # host clock when its answer was on the host
     answer: Optional[np.ndarray]
@@ -65,9 +65,9 @@ class Request:
 def closed_loop(serve: Callable, pool: np.ndarray, seq: np.ndarray,
                 until_s: float, first: int = 0) -> List[Request]:
     """Send requests back to back until the host clock passes ``until_s``.
-    ``serve(image)`` returns the answer, or raises, or returns None for
-    an answer that the system itself reports as failed.  Request ``i``
-    takes image ``seq[(first + i) % len(seq)]``."""
+    ``serve(x)`` returns the answer, or raises, or returns None for an
+    answer that the system itself reports as failed.  Request ``i`` takes
+    input ``seq[(first + i) % len(seq)]``."""
     out: List[Request] = []
     i = first
     while True:
